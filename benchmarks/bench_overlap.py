@@ -88,9 +88,8 @@ for npods in pods:
             np.testing.assert_allclose(ovl, bar, rtol=1e-6, atol=1e-6)
             b_us = med_us(lambda: (sp(vr) if k == 1 else sp.matmat(Vr)).block_until_ready(), iters)
             o_us = med_us(lambda: (ov(vr) if k == 1 else ov.matmat(Vr)).block_until_ready(), iters)
-            # the tile granularity actually executed: SpMV tiles at k=1,
-            # SpMM tiles otherwise
-            itf = (ov.row_split if k == 1 else ov.row_split_mm).interior_tile_fraction
+            # the tile granularity actually executed (SpMV and SpMM share it)
+            itf = ov.row_split.interior_tile_fraction
             # overlap-aware model at comm scale: interior compute sized to
             # the best barrier comm time, split by the interior tile fraction
             pat = sp.partition.pattern.to_comm_pattern()

@@ -32,9 +32,17 @@ def emit(name: str, us_per_call: float, derived: str = "") -> None:
 
 
 def run_with_devices(code: str, devices: int, timeout: int = 900) -> str:
+    """Run ``code`` in a child on ``devices`` forced CPU host devices.
+
+    The child is pinned to the CPU (``JAX_PLATFORMS=cpu``), so it never
+    competes for an accelerator this process may hold; whatever it times is
+    a host-device time, and this function says so on stdout.
+    """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    print(f"# host-device run: {devices} forced CPU devices, not accelerator times")
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,

@@ -310,6 +310,9 @@ def maybe_write_record(report: dict, wanted, section_names, path: str = BENCH_JS
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         bench_chaos,
         bench_kernels,

@@ -113,6 +113,7 @@ def main() -> None:
     # 3. device executor + hierarchical reductions (8 forced host chips;
     #    XLA_FLAGS must be set before jax import, hence the re-launch)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # never compete for an accelerator
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["_KS_CHILD"] = "1"
     if fused:

@@ -52,6 +52,7 @@ def main() -> None:
     # 3. execute all strategies on 8 host devices and verify
     if os.environ.get("_QS_CHILD") != "1":
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"  # never compete for an accelerator
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         env["_QS_CHILD"] = "1"
         env["PYTHONPATH"] = os.pathsep.join(sys.path)

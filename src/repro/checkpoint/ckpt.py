@@ -9,11 +9,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
-
 
 def _flatten(tree) -> Dict[str, np.ndarray]:
-    flat = tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     out = {}
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
@@ -22,7 +20,7 @@ def _flatten(tree) -> Dict[str, np.ndarray]:
 
 
 def _unflatten_into(template, arrays: Dict[str, np.ndarray], shardings=None):
-    flat, treedef = tree_flatten_with_path(template)
+    flat, treedef = jax.tree.flatten_with_path(template)
     shard_flat = (
         jax.tree.leaves(shardings) if shardings is not None else [None] * len(flat)
     )

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import compression
-from repro.compat import axis_size
 
 
 def _flatten_pad(x: jnp.ndarray, n: int) -> Tuple[jnp.ndarray, int]:
@@ -53,7 +52,7 @@ def psum_hierarchical(
     Must be called inside ``shard_map`` with both axes in scope.  Returns the
     reduced array (and the new compression residual if ``compressor``).
     """
-    n_in = axis_size(inner_axis)
+    n_in = jax.lax.axis_size(inner_axis)
     flat, pad = _flatten_pad(x, n_in)
     shard = jax.lax.psum_scatter(
         flat.reshape(n_in, -1), inner_axis, scatter_dimension=0, tiled=False
@@ -137,8 +136,8 @@ def all_to_all_hierarchical(
     with pod-fused inter-pod messages (the 3-Step/2-Step hybrid the paper
     calls 2-Step when every chip stays active).
     """
-    n_out = axis_size(outer_axis)
-    n_in = axis_size(inner_axis)
+    n_out = jax.lax.axis_size(outer_axis)
+    n_in = jax.lax.axis_size(inner_axis)
     blk = x.shape[0] // (n_out * n_in)
     rest = x.shape[1:]
     # [n_out, n_in * blk, ...]: fuse per destination pod
@@ -180,7 +179,7 @@ def sync_grad_tree(
     "hierarchical" (paper technique).  With ``compressor``, returns
     ``(grads, new_residuals)`` implementing error feedback on the DCI hop.
     """
-    ndev = axis_size(outer_axis) * axis_size(inner_axis)
+    ndev = jax.lax.axis_size(outer_axis) * jax.lax.axis_size(inner_axis)
 
     def one(leaf, res):
         if mode == "flat":

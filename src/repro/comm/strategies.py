@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.comm import compression
 from repro.comm import faults as faults_mod
 from repro.comm import wire as wire_mod
@@ -65,6 +64,7 @@ from repro.comm.topology import (
     WORLD_AXES,
     PodTopology,
     make_exchange_mesh,
+    shard_ranks,
 )
 
 # ---------------------------------------------------------------------------
@@ -323,7 +323,7 @@ class TraceableExchange:
     integrity-check metadata) that traces into the program as constants, so
     a ``TraceableExchange`` can sit inside a ``lax.while_loop`` body, a
     scanned pipeline stage, or the barrier executor alike -- the jitted
-    executor of :class:`IrregularExchange` is now just ``shard_map(run)``.
+    executor of :class:`IrregularExchange` is now just ``jax.shard_map(run)``.
 
     Build one with :func:`traceable_exchange` (or
     :meth:`IrregularExchange.traceable`).
@@ -409,11 +409,14 @@ class TraceableExchange:
 
 def traceable_exchange(
     sp: StagePlan,
+    mesh: jax.sharding.Mesh,
     codec: str = "none",
     verify: bool = False,
     faults: Optional[faults_mod.FaultPlan] = None,
 ) -> TraceableExchange:
     """Lower a planned stage program to its traceable program value.
+
+    The plan arrays are placed on ``mesh``, one rank's slice per device.
 
     This is the programmatic form of what :func:`_executor` wraps in
     ``shard_map`` for the barrier path; fused consumers
@@ -447,7 +450,7 @@ def traceable_exchange(
         emit_checks=verify and bool(checks),
         fault_ops=fault_ops,
         delay_s=delay_s,
-        plan_arrays=tuple(jnp.asarray(a) for a in lp.arrays),
+        plan_arrays=tuple(shard_ranks(a, mesh) for a in lp.arrays),
     )
 
 
@@ -748,7 +751,8 @@ def _executor(
     def build():
         # the barrier executor is now just shard_map over the traceable
         # program value; fused consumers embed tx.run in their own loops
-        tx = traceable_exchange(sp, codec=codec, verify=verify, faults=faults)
+        tx = traceable_exchange(sp, codec=codec, verify=verify, faults=faults,
+                                mesh=mesh)
         emit = tx.emit_checks
         specs = (P(WORLD_AXES),) * (1 + len(tx.plan_arrays))
         out_specs = (P(WORLD_AXES), P(WORLD_AXES)) if emit else P(WORLD_AXES)
@@ -763,7 +767,7 @@ def _executor(
             run = tx.run
 
         fn = jax.jit(
-            shard_map(run, mesh=mesh, in_specs=specs, out_specs=out_specs)
+            jax.shard_map(run, mesh=mesh, in_specs=specs, out_specs=out_specs)
         )
         meta = _ExecMeta(emit_checks=emit, checks=tx.checks, delay_s=tx.delay_s)
         return fn, tx.plan_arrays, meta
@@ -781,35 +785,43 @@ def _executor(
 # ---------------------------------------------------------------------------
 
 
-def _build_merge(sp: SplitPhase):
-    """Jitted per-rank gather assembling the full canonical buffer from the
-    two phase outputs (no communication; sharding of axis 0 is preserved)."""
-    mask = jnp.asarray(sp.from_local)
-    valid = jnp.asarray(sp.valid)
-    li = jnp.asarray(sp.local_idx)
-    ri = jnp.asarray(sp.remote_idx)
+def merge_shard(mask, valid, li, ri, local_out, remote_out):
+    """Split-phase merge of one shard: assemble the canonical ``[1, H, ...]``
+    recv buffer from the on-pod and inter-pod phase outputs by per-rank
+    gathers (no communication)."""
+    nfeat = local_out.ndim - 2
 
-    @jax.jit
-    def merge(local_out, remote_out):
-        nfeat = local_out.ndim - 2
+    def take(buf, idx):
+        idx = jnp.minimum(idx, buf.shape[1] - 1)
+        idx = idx.reshape(idx.shape + (1,) * nfeat)
+        idx = jnp.broadcast_to(idx, idx.shape[:2] + buf.shape[2:])
+        return jnp.take_along_axis(buf, idx, axis=1)
 
-        def take(buf, idx):
-            idx = jnp.minimum(idx, buf.shape[1] - 1)
-            idx = idx.reshape(idx.shape + (1,) * nfeat)
-            idx = jnp.broadcast_to(idx, idx.shape[:2] + buf.shape[2:])
-            return jnp.take_along_axis(buf, idx, axis=1)
+    m = mask.reshape(mask.shape + (1,) * nfeat)
+    v = valid.reshape(valid.shape + (1,) * nfeat)
+    lo = take(local_out, li)
+    merged = jnp.where(m, lo, take(remote_out, ri))
+    return jnp.where(v, merged, jnp.zeros_like(lo))
 
-        m = mask.reshape(mask.shape + (1,) * nfeat)
-        v = valid.reshape(valid.shape + (1,) * nfeat)
-        lo = take(local_out, li)
-        merged = jnp.where(m, lo, take(remote_out, ri))
-        return jnp.where(v, merged, jnp.zeros_like(lo))
 
-    return merge
+def _build_merge(sp: SplitPhase, mesh: jax.sharding.Mesh):
+    """Jitted :func:`merge_shard` over ``mesh``; its index maps are placed
+    on the mesh once and passed as arguments."""
+    maps = tuple(
+        shard_ranks(a, mesh)
+        for a in (sp.from_local, sp.valid, sp.local_idx, sp.remote_idx)
+    )
+    merge = jax.jit(
+        jax.shard_map(
+            merge_shard, mesh=mesh, in_specs=(P(WORLD_AXES),) * 6,
+            out_specs=P(WORLD_AXES),
+        )
+    )
+    return lambda local_out, remote_out: merge(*maps, local_out, remote_out)
 
 
 class _LazyMerge:
-    """Builds the jitted split-phase merge on first call.
+    """Builds the jitted split-phase merge on first call, once per mesh.
 
     Laziness matters because the jax-free consumers of the split cache
     (:class:`repro.solve.operator.NumpySpMV`) only need the decomposition;
@@ -817,16 +829,18 @@ class _LazyMerge:
     for a function they never invoke.
     """
 
-    __slots__ = ("_sp", "_fn")
+    __slots__ = ("_sp", "_fns")
 
     def __init__(self, sp: SplitPhase):
         self._sp = sp
-        self._fn = None
+        self._fns: Dict[jax.sharding.Mesh, object] = {}
 
     def __call__(self, local_out, remote_out):
-        if self._fn is None:
-            self._fn = _build_merge(self._sp)
-        return self._fn(local_out, remote_out)
+        mesh = local_out.sharding.mesh
+        fn = self._fns.get(mesh)
+        if fn is None:
+            fn = self._fns[mesh] = _build_merge(self._sp, mesh)
+        return fn(local_out, remote_out)
 
 
 def _split_phase_cached(pattern: ExchangePattern) -> tuple:
@@ -984,7 +998,7 @@ class IrregularExchange:
         if self._traceable is None:
             self._traceable = traceable_exchange(
                 self.plan, codec=self.wire, verify=self.verify,
-                faults=self.faults,
+                faults=self.faults, mesh=self.mesh,
             )
         return self._traceable
 

@@ -13,6 +13,8 @@ import dataclasses
 from typing import List, Tuple
 
 import jax
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 POD_AXIS = "pod"
 LOCAL_AXIS = "local"
@@ -65,3 +67,9 @@ def make_exchange_mesh(topology: PodTopology) -> jax.sharding.Mesh:
             f"have {jax.device_count()}"
         )
     return jax.make_mesh((topology.npods, topology.ppn), WORLD_AXES)
+
+
+def shard_ranks(a, mesh: jax.sharding.Mesh) -> jax.Array:
+    """Place a ``[nranks, ...]`` array so each device of ``mesh`` holds only
+    its own rank's slice (the layout every ``shard_map`` here expects)."""
+    return jax.device_put(a, NamedSharding(mesh, P(WORLD_AXES)))

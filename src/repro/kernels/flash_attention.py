@@ -78,7 +78,8 @@ def flash_attention_kernel(
     scale: Optional[float] = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]

@@ -1,7 +1,9 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels: the one place the mode is chosen.
 
-Selects interpret mode automatically on non-TPU backends so the same call
-sites run in this CPU container (correctness) and on real TPUs (performance).
+On a TPU the kernels compile with Mosaic; on any other backend they run in
+the Pallas interpreter, which is how the CPU tests check them against
+:mod:`repro.kernels.ref`.  A kernel that Mosaic refuses raises: nothing here
+falls back to the reference.
 """
 
 from __future__ import annotations
@@ -9,10 +11,9 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels import spmv_ell as _ell
 from repro.kernels.flash_attention import flash_attention_kernel
-from repro.kernels.spmv_ell import spmv_ell as _spmv_ell
 from repro.kernels.ssd_scan import ssd_scan_kernel
 
 
@@ -20,9 +21,14 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def spmv_ell(data, cols, x):
+def spmv_ell(data, cols, x, tile_mask=None):
     """Blocked-ELL SpMV: ``w[i] = sum_k data[i,k] * x[cols[i,k]]``."""
-    return _spmv_ell(data, cols, x, interpret=_interpret())
+    return _ell.spmv_ell(data, cols, x, interpret=_interpret(), tile_mask=tile_mask)
+
+
+def spmm_ell(data, cols, x, tile_mask=None):
+    """Blocked-ELL SpMM: ``W[i,c] = sum_k data[i,k] * x[cols[i,k], c]``."""
+    return _ell.spmm_ell(data, cols, x, interpret=_interpret(), tile_mask=tile_mask)
 
 
 def flash_attention(q, k, v, causal: bool = True, window: Optional[int] = None,

@@ -10,46 +10,43 @@ halo exchange).
 TPU adaptation (vs. a CUDA CSR kernel):
 
 * CSR's per-row variable nnz maps badly onto the VPU's (8, 128) vregs; we use
-  ELL padding so every row tile is a dense ``[TILE_R, K]`` rectangle -- the
-  padding slots carry ``data == 0`` so they contribute nothing.
-* The row dimension is tiled with a ``BlockSpec`` grid so each step's working
-  set (``TILE_R x K`` data/cols plus the gathered values) sits in VMEM.
-* The source vector ``x`` is kept whole in VMEM (halo buffers in this system
-  are << VMEM; a multi-megarow vector would need a two-phase
-  gather-then-reduce kernel instead).
-* The inner gather uses ``jnp.take`` which lowers to Mosaic's dynamic-gather;
-  K is padded to a multiple of 128 so the multiply-accumulate is lane-aligned.
+  ELL padding so every row tile is a dense rectangle -- the padding slots
+  carry ``data == 0`` so they contribute nothing.
+* Rows ride the 128-wide lane axis: the kernel sees ``data`` as ``[K, R]``
+  and the gathered source values as ``[K, R]`` (SpMV) or ``[K, C, R]``
+  (SpMM), so the products and every output row tile are lane-dense.
+* The gather ``x[cols]`` runs in XLA before the ``pallas_call``.  Mosaic
+  lowers only ``take_along_axis``-shaped gathers (indices shaped like the
+  operand), which cannot read an arbitrary ``x[cols]``; the XLA gather also
+  keeps ``x`` out of VMEM, so neither kernel is bounded by the source
+  vector's size.  It costs one ``K x C x R`` pass through HBM per call.
+* The row dimension is tiled with a ``BlockSpec`` grid of ``TILE_R`` rows;
+  SpMM adds a grid axis of ``TILE_C`` rhs columns (one sublane tile), so each
+  step's working set is ``K x TILE_C x TILE_R`` whatever ``k`` is.
 
-SpMM column-tiling design (why a second grid axis instead of a wider SpMV):
-
-* The grid is ``row tiles x column tiles`` of the rhs: step ``(i, c)``
-  gathers ``X[cols, c-tile]`` and contracts ``[TILE_R, K] @ gather`` into one
-  ``[TILE_R, TILE_C]`` output tile.  ``TILE_C = 128`` makes every rhs tile
-  exactly one lane tile wide, so each gathered row of ``X`` is a full vreg
-  row and the broadcast-multiply-reduce stays lane-aligned for any ``k``.
-* ``TILE_R`` shrinks from 256 (SpMV) to 64: the gathered operand is now
-  ``[TILE_R, K, TILE_C]`` rather than ``[TILE_R, K]``, and the VMEM budget
-  that held one row-tile's vector gather must hold a full lane tile per
-  matrix slot (64 x 128 x 128 x 4B = 4 MiB at K = 128).
-* Column tiles are *independent grid steps*, not an inner loop: the same
-  ``data``/``cols`` row tile is re-streamed once per column tile instead of
-  keeping a ``[TILE_R, k]`` accumulator live across the sweep.  That bounds
-  VMEM independently of ``k`` (k = 64 costs the ELL block being re-read
-  ``ceil(k/128)`` times, i.e. once) and keeps the k = 1 path numerically
-  identical to :func:`spmv_ell`: same ``K`` padding, same reduction order,
-  one degenerate column tile.
+Reduction: both kernels sum the f32 products with one reduce over the K
+axis.  Interpreted, that reduce runs in the same order for SpMV and for a
+single-column SpMM, which therefore agree bit for bit (the tests pin it);
+compiled, SpMV reduces across sublanes and SpMM across vregs, so the two may
+differ in the last bit.
 
 Row-tile masking (the split-phase/overlap hook):
 
-* Both kernels accept an optional ``tile_mask`` -- one int per row tile.
-  Inactive tiles (mask 0) are *skipped* via ``pl.when`` (zero-filled output,
-  no gather, no multiply-accumulate), so both passes of the overlapped
-  distributed SpMV reuse ONE kernel: the diag pass runs every row tile while
-  the inter-node exchange is in flight, and the off pass afterwards runs
-  only the boundary tiles (interior tiles' off-block rows are pure padding).
-  An active tile's compute is instruction-identical to the unmasked kernel,
-  which is what makes the overlapped path bit-compatible with the barrier
-  path.
+* Both kernels accept an optional ``tile_mask`` -- one int per row tile,
+  passed as a scalar-prefetch operand (all ones when omitted).  Inactive
+  tiles (mask 0) skip the multiply-reduce via ``pl.when`` and deliver
+  zeros, so both passes of the overlapped distributed SpMV reuse ONE
+  kernel: the diag pass runs every row tile while the inter-node exchange
+  is in flight, and the off pass afterwards reduces only the boundary tiles
+  (interior tiles' off-block rows are pure padding).  The mask does *not*
+  reach the XLA gather before the kernel: a masked call still gathers
+  ``x[cols]`` for every row, inactive tiles included, so it saves the
+  reduce but not the gather.  Masked and unmasked calls run the same kernel
+  body, which is what makes the overlapped path bit-compatible with the
+  barrier path.
+
+Both kernels take ``interpret`` without a default: the main path reaches
+them through :mod:`repro.kernels.ops`, which picks the mode from the backend.
 """
 
 from __future__ import annotations
@@ -59,47 +56,27 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-TILE_R = 256  # rows per SpMV grid step
-TILE_R_MM = 64  # rows per SpMM grid step (gather working set is TILE_C x wider)
-TILE_C = 128  # rhs columns per SpMM grid step = one lane tile
-LANE = 128  # TPU lane width
-
-
-def _spmv_ell_kernel(data_ref, cols_ref, x_ref, out_ref):
-    data = data_ref[...]  # [TILE_R, K]
-    cols = cols_ref[...]  # [TILE_R, K]
-    x = x_ref[...]  # [N]
-    gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(cols.shape)
-    out_ref[...] = (data * gathered).sum(axis=1)
+TILE_R = 2048  # rows per grid step (lanes)
+TILE_C = 8  # rhs columns per SpMM grid step (one sublane tile)
 
 
-def _spmv_ell_masked_kernel(mask_ref, data_ref, cols_ref, x_ref, out_ref):
-    @pl.when(mask_ref[0] != 0)
+def _ell_kernel(mask_ref, data_ref, g_ref, out_ref):
+    # data [K, T]; g [K, T] -> out [T] (SpMV) or g [K, C, T] -> out [C, T];
+    # products and the sum are f32 whatever the storage dtype
+    spmm = len(g_ref.shape) == 3
+    active = mask_ref[pl.program_id(0)] != 0
+
+    @pl.when(active)
     def _active():
-        _spmv_ell_kernel(data_ref, cols_ref, x_ref, out_ref)
+        d = data_ref[...].astype(jnp.float32)
+        if spmm:
+            d = d[:, None, :]
+        prod = d * g_ref[...].astype(jnp.float32)
+        out_ref[...] = prod.sum(axis=0).astype(out_ref.dtype)
 
-    @pl.when(mask_ref[0] == 0)
-    def _inactive():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
-
-
-def _spmm_ell_kernel(data_ref, cols_ref, x_ref, out_ref):
-    data = data_ref[...]  # [TILE_R_MM, K]
-    cols = cols_ref[...]  # [TILE_R_MM, K]
-    x = x_ref[...]  # [N, TILE_C]
-    gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(
-        cols.shape + (x.shape[-1],)
-    )  # [TILE_R_MM, K, TILE_C]
-    out_ref[...] = (data[..., None] * gathered).sum(axis=1)
-
-
-def _spmm_ell_masked_kernel(mask_ref, data_ref, cols_ref, x_ref, out_ref):
-    @pl.when(mask_ref[0] != 0)
-    def _active():
-        _spmm_ell_kernel(data_ref, cols_ref, x_ref, out_ref)
-
-    @pl.when(mask_ref[0] == 0)
+    @pl.when(jnp.logical_not(active))
     def _inactive():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
@@ -113,12 +90,24 @@ def _pad_to(a: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
     return jnp.pad(a, widths)
 
 
-def num_row_tiles(rows: int, tile_rows: int) -> int:
+def _transpose_blocks(data, cols):
+    """``[R, K]`` ELL storage -> rows-on-lanes ``[K, Rp]``, zero-padded to
+    whole row tiles (padding slots read ``x[0]`` with weight 0)."""
+    return _pad_to(data.T, TILE_R, 1), _pad_to(cols.T, TILE_R, 1)
+
+
+def num_row_tiles(rows: int) -> int:
     """Grid length (= ``tile_mask`` length) for ``rows`` ELL rows."""
-    return -(-rows // tile_rows)
+    return -(-rows // TILE_R)
 
 
-def _check_mask(tile_mask: jnp.ndarray, ntiles: int) -> jnp.ndarray:
+def _tile_mask(tile_mask, ntiles: int) -> jnp.ndarray:
+    if tile_mask is None:
+        # an all-ones mask the compiler cannot see: a constant one lets XLA
+        # drop the tile branch of the interpreted kernel and compile its
+        # body differently from a masked call's, so the two would no longer
+        # agree bit for bit
+        return jax.lax.optimization_barrier(jnp.ones((ntiles,), jnp.int32))
     if tile_mask.shape != (ntiles,):
         raise ValueError(
             f"tile_mask must have shape ({ntiles},) for this row count, "
@@ -127,45 +116,51 @@ def _check_mask(tile_mask: jnp.ndarray, ntiles: int) -> jnp.ndarray:
     return tile_mask.astype(jnp.int32)
 
 
+def _ell_call(data_t, g, grid, g_spec, out_spec, out_shape, tile_mask, interpret):
+    """Run the row-tiled kernel over ``data_t [K, Rp]`` and gathered ``g``;
+    the tile mask (all ones when ``None``) is a scalar-prefetch operand."""
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[pl.BlockSpec((data_t.shape[0], TILE_R), lambda i, *_: (0, i)), g_spec],
+        out_specs=out_spec,
+    )
+    return pl.pallas_call(
+        _ell_kernel,
+        grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, data_t.dtype),
+        interpret=interpret,
+    )(_tile_mask(tile_mask, grid[0]), data_t, g)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def spmv_ell(
     data: jnp.ndarray,
     cols: jnp.ndarray,
     x: jnp.ndarray,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     tile_mask: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """``w = A @ x`` for an ELL block. data/cols: [R, K]; x: [N] -> w: [R].
 
-    ``tile_mask`` (optional ``[num_row_tiles(R, TILE_R)]`` ints) selects
-    which row tiles compute; inactive tiles are skipped and deliver zeros.
+    ``tile_mask`` (optional ``[num_row_tiles(R)]`` ints) selects which row
+    tiles reduce; inactive tiles deliver zeros (their rows are still
+    gathered).
     """
-    R, K = data.shape
-    data_p = _pad_to(_pad_to(data, LANE, 1), TILE_R, 0)
-    cols_p = _pad_to(_pad_to(cols, LANE, 1), TILE_R, 0)
-    x_p = _pad_to(x, LANE, 0)
-    Rp, Kp = data_p.shape
-    grid = (num_row_tiles(R, TILE_R),)
-    in_specs = [
-        pl.BlockSpec((TILE_R, Kp), lambda i: (i, 0)),
-        pl.BlockSpec((TILE_R, Kp), lambda i: (i, 0)),
-        pl.BlockSpec((x_p.shape[0],), lambda i: (0,)),
-    ]
-    if tile_mask is None:
-        kernel, args = _spmv_ell_kernel, (data_p, cols_p, x_p)
-    else:
-        mask = _check_mask(tile_mask, grid[0])
-        kernel = _spmv_ell_masked_kernel
-        in_specs = [pl.BlockSpec((1,), lambda i: (i,))] + in_specs
-        args = (mask, data_p, cols_p, x_p)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((TILE_R,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Rp,), data.dtype),
+    R = data.shape[0]
+    data_t, cols_t = _transpose_blocks(data, cols)  # [K, Rp]
+    g = x[cols_t]
+    out = _ell_call(
+        data_t,
+        g,
+        grid=(num_row_tiles(R),),
+        g_spec=pl.BlockSpec((data_t.shape[0], TILE_R), lambda i, *_: (0, i)),
+        out_spec=pl.BlockSpec((TILE_R,), lambda i, *_: (i,)),
+        out_shape=(data_t.shape[1],),
+        tile_mask=tile_mask,
         interpret=interpret,
-    )(*args)
+    )
     return out[:R]
 
 
@@ -174,40 +169,31 @@ def spmm_ell(
     data: jnp.ndarray,
     cols: jnp.ndarray,
     x: jnp.ndarray,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     tile_mask: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """``W = A @ X`` for an ELL block. data/cols: [R, K]; x: [N, C] -> [R, C].
 
-    ``tile_mask`` (optional ``[num_row_tiles(R, TILE_R_MM)]`` ints) selects
-    which row tiles compute; inactive tiles are skipped and deliver zeros.
+    ``tile_mask`` (optional ``[num_row_tiles(R)]`` ints) selects which row
+    tiles reduce; inactive tiles deliver zeros (their rows are still
+    gathered).
     """
-    R, K = data.shape
-    N, C = x.shape
-    data_p = _pad_to(_pad_to(data, LANE, 1), TILE_R_MM, 0)
-    cols_p = _pad_to(_pad_to(cols, LANE, 1), TILE_R_MM, 0)
-    x_p = _pad_to(_pad_to(x, TILE_C, 1), 8, 0)
-    Rp, Kp = data_p.shape
-    Np, Cp = x_p.shape
-    grid = (num_row_tiles(R, TILE_R_MM), Cp // TILE_C)
-    in_specs = [
-        pl.BlockSpec((TILE_R_MM, Kp), lambda i, c: (i, 0)),
-        pl.BlockSpec((TILE_R_MM, Kp), lambda i, c: (i, 0)),
-        pl.BlockSpec((Np, TILE_C), lambda i, c: (0, c)),
-    ]
-    if tile_mask is None:
-        kernel, args = _spmm_ell_kernel, (data_p, cols_p, x_p)
-    else:
-        mask = _check_mask(tile_mask, grid[0])
-        kernel = _spmm_ell_masked_kernel
-        in_specs = [pl.BlockSpec((1,), lambda i, c: (i,))] + in_specs
-        args = (mask, data_p, cols_p, x_p)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((TILE_R_MM, TILE_C), lambda i, c: (i, c)),
-        out_shape=jax.ShapeDtypeStruct((Rp, Cp), data.dtype),
+    R = data.shape[0]
+    C = x.shape[1]
+    tc = min(C, TILE_C)
+    x_t = _pad_to(x, tc, 1).T  # [Cp, N]
+    data_t, cols_t = _transpose_blocks(data, cols)  # [K, Rp]
+    g = jnp.moveaxis(x_t[:, cols_t], 0, 1)  # [K, Cp, Rp]
+    K, Cp, Rp = g.shape
+    out = _ell_call(
+        data_t,
+        g,
+        grid=(num_row_tiles(R), Cp // tc),
+        g_spec=pl.BlockSpec((K, tc, TILE_R), lambda i, c, *_: (0, c, i)),
+        out_spec=pl.BlockSpec((tc, TILE_R), lambda i, c, *_: (c, i)),
+        out_shape=(Cp, Rp),
+        tile_mask=tile_mask,
         interpret=interpret,
-    )(*args)
-    return out[:R, :C]
+    )
+    return out[:C, :R].T

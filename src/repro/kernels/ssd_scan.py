@@ -60,7 +60,8 @@ def ssd_scan_kernel(
     b: jnp.ndarray,  # [B, S, N]
     c: jnp.ndarray,  # [B, S, N]
     chunk: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, S, H, P = xdt.shape
     N = b.shape[-1]

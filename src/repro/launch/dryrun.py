@@ -28,9 +28,9 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES, get_config, shape_applicable, ARCH_IDS
-from repro.compat import tree_flatten_with_path
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.hlo_analysis import analyze
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import LMModel, param_shardings, rules_for_mesh, spec_for
 from repro.models.sharding import ParamSpec, named_sharding
@@ -94,7 +94,7 @@ def cache_shardings(cache_tree, mesh: Mesh, rules) -> Any:
         logical = logical[:nd] + (None,) * (nd - len(logical))
         return named_sharding(mesh, rules, logical, leaf.shape)
 
-    flat, treedef = tree_flatten_with_path(cache_tree)
+    flat, treedef = jax.tree.flatten_with_path(cache_tree)
     return jax.tree.unflatten(treedef, [one(p, l) for p, l in flat])
 
 
@@ -225,7 +225,7 @@ def model_flops(cfg: ModelConfig, model: LMModel, shape: ShapeConfig) -> float:
     """6*N*D (train) / 2*N*D (inference) with N = active params (MoE-aware)."""
     specs = model.param_specs()
     total = active = 0
-    for path, ps in tree_flatten_with_path(
+    for path, ps in jax.tree.flatten_with_path(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec)
     )[0]:
         n = int(np.prod(ps.shape))
@@ -315,6 +315,7 @@ def main() -> None:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default=os.path.abspath(ARTIFACTS))
     args = ap.parse_args()
+    use_compile_cache()
 
     cells = []
     archs = ARCH_IDS if (args.all or args.arch is None) else [args.arch]

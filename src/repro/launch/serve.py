@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import PRESETS
 from repro.models import ExpertLoadHistogram, LMModel
@@ -99,6 +100,7 @@ def main() -> None:
                          "recovery-ladder outcome: faults, recoveries, sheds, "
                          "breaker probes, deadline misses, trace hash")
     args = ap.parse_args()
+    use_compile_cache()
 
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh = make_host_mesh(d, m)

@@ -18,6 +18,7 @@ import logging
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim import AdamWConfig
 from repro.runtime import Trainer, TrainerConfig
@@ -72,6 +73,7 @@ def main() -> None:
     ap.add_argument("--fail-at", type=int, default=None)
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    use_compile_cache()
 
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh = make_host_mesh(d, m)

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.comm.topology import WORLD_AXES, PodTopology
 from repro.configs.base import MoEConfig
-from repro.compat import shard_map
 from repro.models.layers import MLP
 from repro.models.moe_dispatch import MoEDispatcher
 from repro.models.sharding import ParamSpec
@@ -258,7 +257,7 @@ class MoELayer:
         x_spec = P(batch_axes or None, None, None)
         r_spec = P(batch_axes or None, None, None)
         w_spec = P(ep, None, "model" if "model" in mesh.axis_names else None)
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(x_spec, r_spec, r_spec, w_spec, w_spec,
@@ -438,21 +437,21 @@ class MoELayer:
             return out.astype(dtype)
 
         fns = (
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 stage_send,
                 mesh=mesh,
                 in_specs=(mat, mat, mat),
                 out_specs=(mat, vec, vec, vec, vec),
                 check_vma=False,
             )),
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 stage_expert,
                 mesh=mesh,
                 in_specs=(mat, vec, mat, vec, vec, mat, mat, mat),
                 out_specs=mat,
                 check_vma=False,
             )),
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 stage_combine,
                 mesh=mesh,
                 in_specs=(mat, mat, vec, vec, vec),

@@ -52,6 +52,7 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from repro.comm import strategies as comm_strategies
+from repro.comm.topology import shard_ranks
 from repro.comm.faults import (
     ExchangeIntegrityError,
     HealthTracker,
@@ -260,7 +261,6 @@ def _build_fused(top, shard_dot, solver: str, hist_len: int, eps: float,
     from jax.sharding import PartitionSpec as P
 
     from repro.comm.topology import WORLD_AXES
-    from repro.compat import shard_map
 
     ce = checkpoint_every
 
@@ -455,7 +455,7 @@ def _build_fused(top, shard_dot, solver: str, hist_len: int, eps: float,
     n_in = (7 if resume else 4) + len(top.operands)
     n_out = 8 if ce is None else 12
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=top.mesh,
             in_specs=(P(WORLD_AXES),) * n_in,
@@ -517,13 +517,17 @@ def _fused_entry(op, solver: str, maxiter: int, dtype, compressor,
     return comm_strategies.fused_cached(key, build)
 
 
-def _dispatch(fn, top, b_dev, x0_dev, tol: float, max_it: int, dtype):
-    import jax.numpy as jnp
-
+def _limits(top, tol: float, max_it: int, dtype) -> tuple:
+    """Per-rank tolerance and iteration cap, placed like every operand."""
     g = top.topo.nranks
-    tolt = jnp.full((g, 1), tol, dtype)
-    maxitt = jnp.full((g, 1), max_it, jnp.int32)
-    outs = fn(b_dev, x0_dev, tolt, maxitt, *top.operands)
+    return (
+        shard_ranks(np.full((g, 1), tol, dtype), top.mesh),
+        shard_ranks(np.full((g, 1), max_it, np.int32), top.mesh),
+    )
+
+
+def _dispatch(fn, top, b_dev, x0_dev, tol: float, max_it: int, dtype):
+    outs = _raw_forward(fn, top, b_dev, x0_dev, tol, max_it, dtype)
     x, best_x, hist, it, k, status, mvc, viols = outs[:8]
     if top.verifier is not None:
         top.verifier.raise_viols(np.asarray(viols))
@@ -565,22 +569,13 @@ def _harvest(prev: Optional[_Checkpoint], outs) -> Optional[_Checkpoint]:
 
 
 def _raw_forward(fn, top, b_dev, x0_dev, tol: float, max_it: int, dtype):
-    import jax.numpy as jnp
-
-    g = top.topo.nranks
-    tolt = jnp.full((g, 1), tol, dtype)
-    maxitt = jnp.full((g, 1), max_it, jnp.int32)
-    return fn(b_dev, x0_dev, tolt, maxitt, *top.operands)
+    return fn(b_dev, x0_dev, *_limits(top, tol, max_it, dtype), *top.operands)
 
 
 def _raw_resume(fn, top, b_dev, ck: _Checkpoint, tol: float, max_it: int,
                 dtype):
-    import jax.numpy as jnp
-
-    g = top.topo.nranks
-    tolt = jnp.full((g, 1), tol, dtype)
-    maxitt = jnp.full((g, 1), max_it, jnp.int32)
-    return fn(b_dev, ck.vec, ck.f, ck.i, ck.hist, tolt, maxitt, *top.operands)
+    return fn(b_dev, ck.vec, ck.f, ck.i, ck.hist,
+              *_limits(top, tol, max_it, dtype), *top.operands)
 
 
 def _viol_error(top, viols_np):
@@ -609,8 +604,6 @@ def _unpack(outs):
 def _fused_solve(op, b, x0, tol: float, maxiter: int, reductions,
                  solver: str, checkpoint_every: Optional[int] = None
                  ) -> SolveResult:
-    import jax.numpy as jnp
-
     compressor = getattr(reductions, "compressor", None)
     b = np.asarray(b)
     g, L = op.topo.nranks, op.rows_per_rank
@@ -628,23 +621,23 @@ def _fused_solve(op, b, x0, tol: float, maxiter: int, reductions,
                            residuals=(0.0,), matvecs=0,
                            status=_finish_status("converged", 0, op, rc0))
     dtype = b.dtype
-    b_dev = jnp.asarray(b)
-    x0_arr = (
-        np.zeros_like(b) if x0 is None
-        else np.array(x0, dtype=dtype, copy=True)
+    mesh = getattr(op, "mesh", None) or comm_strategies._default_mesh(op.topo)
+    b_dev = shard_ranks(b, mesh)
+    x0_dev = shard_ranks(
+        np.zeros_like(b) if x0 is None else np.asarray(x0, dtype=dtype), mesh
     )
     # the program always runs the init matvec (for x0=0 it computes
     # b - A@0 = b exactly); the host loops only count it when x0 is given
     init_mv_adjust = 1 if x0 is None else 0
     if checkpoint_every is not None:
         return _fused_solve_resumable(
-            op, b, b_dev, x0_arr, tol, maxiter, dtype, compressor, solver,
+            op, b, b_dev, x0_dev, tol, maxiter, dtype, compressor, solver,
             checkpoint_every, rc0, init_mv_adjust,
         )
     fn, top = _fused_entry(op, solver, maxiter, dtype, compressor)
 
     x, best_x, hist, it, status, mvc, = _dispatch(
-        fn, top, b_dev, jnp.asarray(x0_arr), tol, maxiter, dtype
+        fn, top, b_dev, x0_dev, tol, maxiter, dtype
     )
     restarts = 0
     matvecs = mvc - init_mv_adjust
@@ -686,7 +679,7 @@ def _fused_solve(op, b, x0, tol: float, maxiter: int, reductions,
     )
 
 
-def _fused_solve_resumable(op, b, b_dev, x0_arr, tol: float, maxiter: int,
+def _fused_solve_resumable(op, b, b_dev, x0_dev, tol: float, maxiter: int,
                            dtype, compressor, solver: str, ce: int, rc0,
                            init_mv_adjust: int) -> SolveResult:
     """The checkpoint/resume host wrapper around the fused program.
@@ -701,10 +694,8 @@ def _fused_solve_resumable(op, b, b_dev, x0_arr, tol: float, maxiter: int,
     own per-halo ladder) from the same checkpoint.  ``SolveResult.status``
     records ``+resume:<n>``.
     """
-    import jax.numpy as jnp
-
     fn, top = _fused_entry(op, solver, maxiter, dtype, compressor, ce)
-    outs = _raw_forward(fn, top, b_dev, jnp.asarray(x0_arr), tol, maxiter,
+    outs = _raw_forward(fn, top, b_dev, x0_dev, tol, maxiter,
                         dtype)
     state = {"ck": _harvest(None, outs), "used": False}
     err = _viol_error(top, np.asarray(outs[7]))
@@ -729,7 +720,7 @@ def _fused_solve_resumable(op, b, b_dev, x0_arr, tol: float, maxiter: int,
             else:
                 fnv, topv = _fused_entry(vop, solver, maxiter, dtype,
                                          compressor, ce)
-                o = _raw_forward(fnv, topv, b_dev, jnp.asarray(x0_arr), tol,
+                o = _raw_forward(fnv, topv, b_dev, x0_dev, tol,
                                  maxiter, dtype)
             state["ck"] = _harvest(state["ck"], o)
             e = _viol_error(topv, np.asarray(o[7]))

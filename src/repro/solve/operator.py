@@ -263,22 +263,18 @@ class TraceableOperator:
 
     # -- per-shard kernels ---------------------------------------------
     def _full(self, data, cols, x):
+        from repro.kernels import ops, ref
+
         if self.use_pallas:
-            from repro.kernels.spmv_ell import spmv_ell
-
-            return spmv_ell(data, cols, x, interpret=True)
-        from repro.kernels import ref as kref
-
-        return kref.spmv_ell(data, cols, x)
+            return ops.spmv_ell(data, cols, x)
+        return ref.spmv_ell(data, cols, x)
 
     def _masked(self, data, cols, x, tiles, rows):
+        from repro.kernels import ops, ref
+
         if self.use_pallas:
-            from repro.kernels.spmv_ell import spmv_ell
-
-            return spmv_ell(data, cols, x, interpret=True, tile_mask=tiles)
-        from repro.kernels import ref as kref
-
-        return kref.spmv_ell_masked(data, cols, x, rows)
+            return ops.spmv_ell(data, cols, x, tile_mask=tiles)
+        return ref.spmv_ell_masked(data, cols, x, rows)
 
     # ------------------------------------------------------------------
     def matvec(self, v, *operands):
@@ -294,8 +290,6 @@ class TraceableOperator:
         return self._apply(v, operands, verified=True)
 
     def _apply(self, v, operands, verified: bool):
-        import jax.numpy as jnp
-
         k = self.n_exchange_ops
         if not self.overlap:
             pa, (dd, dc, od, oc) = operands[:k], operands[k:]
@@ -314,7 +308,9 @@ class TraceableOperator:
         # merged halo exactly like the host pipeline's finish()
         remote_out, viols = self._run_exchange(self.remote, v, rpa, verified)
         local_out = self.local.run(v, *lpa)
-        halo = _merge_shard(mask, valid, li, ri, local_out, remote_out)
+        halo = comm_strategies.merge_shard(
+            mask, valid, li, ri, local_out, remote_out
+        )
         w = self._masked(dd[0], dc[0], v[0], all_tiles[0], all_rows[0])
         w = w + self._masked(od[0], oc[0], halo[0], bnd_tiles[0], bnd_rows[0])
         return w[None], viols
@@ -328,26 +324,6 @@ class TraceableOperator:
         return tx.run(v, *plan_arrays), jnp.zeros((0,), jnp.float32)
 
 
-def _merge_shard(mask, valid, li, ri, local_out, remote_out):
-    """Per-shard split-phase merge -- the ``[1, H]``-sliced twin of
-    :func:`repro.comm.strategies._build_merge`'s jitted gather."""
-    import jax.numpy as jnp
-
-    nfeat = local_out.ndim - 2
-
-    def take(buf, idx):
-        idx = jnp.minimum(idx, buf.shape[1] - 1)
-        idx = idx.reshape(idx.shape + (1,) * nfeat)
-        idx = jnp.broadcast_to(idx, idx.shape[:2] + buf.shape[2:])
-        return jnp.take_along_axis(buf, idx, axis=1)
-
-    m = mask.reshape(mask.shape + (1,) * nfeat)
-    v = valid.reshape(valid.shape + (1,) * nfeat)
-    lo = take(local_out, li)
-    merged = jnp.where(m, lo, take(remote_out, ri))
-    return jnp.where(v, merged, jnp.zeros_like(lo))
-
-
 def traceable_operator(op) -> TraceableOperator:
     """Lower either SpMV executor flavor to its traceable program value.
 
@@ -356,17 +332,15 @@ def traceable_operator(op) -> TraceableOperator:
     are transferred, the jnp-oracle kernels are used, and the mesh is the
     default exchange mesh).  Plans come from the same module caches as the
     host executors, so lowering an already-constructed operator re-plans
-    nothing.
+    nothing.  Every operand is placed on the mesh with one rank's slice per
+    device.
     """
-    import jax.numpy as jnp
-
     from repro.comm.strategies import _default_mesh, traceable_exchange
-    from repro.core.split_plan import split_rows
-    from repro.kernels.spmv_ell import TILE_R
+    from repro.comm.topology import shard_ranks
+    from repro.sparse.spmv import phase_masks, row_split
 
     part = op.partition
     topo, L = part.topo, part.rows_per_rank
-    g = topo.nranks
     is_device = hasattr(op, "use_pallas")
     use_pallas = bool(getattr(op, "use_pallas", False))
     mesh = getattr(op, "mesh", None) or _default_mesh(topo)
@@ -378,7 +352,7 @@ def traceable_operator(op) -> TraceableOperator:
         blocks = op._blocks
     else:
         blocks = tuple(
-            jnp.asarray(a)
+            shard_ranks(a, mesh)
             for a in (op._diag_d, op._diag_c, op._off_d, op._off_c)
         )
 
@@ -387,7 +361,7 @@ def traceable_operator(op) -> TraceableOperator:
             tx = op.exchange.traceable()
         else:
             tx = traceable_exchange(op._plan, codec=wire, verify=verify,
-                                    faults=faults)
+                                    faults=faults, mesh=mesh)
         return TraceableOperator(
             topo=topo, local_size=L, overlap=False, use_pallas=use_pallas,
             mesh=mesh, exchange=tx, remote=None, local=None,
@@ -404,24 +378,13 @@ def traceable_operator(op) -> TraceableOperator:
         sp.local, "local", fuse_program=getattr(op, "fuse_program", True)
     )
     tx_remote = traceable_exchange(remote_plan, codec=wire, verify=verify,
-                                   faults=faults)
-    tx_local = traceable_exchange(local_plan)
-    merge_ops = (
-        jnp.asarray(sp.from_local),
-        jnp.asarray(sp.valid),
-        jnp.asarray(sp.local_idx),
-        jnp.asarray(sp.remote_idx),
+                                   faults=faults, mesh=mesh)
+    tx_local = traceable_exchange(local_plan, mesh=mesh)
+    merge_ops = tuple(
+        shard_ranks(a, mesh)
+        for a in (sp.from_local, sp.valid, sp.local_idx, sp.remote_idx)
     )
-    halo_dep = part.off_row_nnz.reshape(g, L) > 0
-    split = split_rows(halo_dep, TILE_R)
-    bnd = split.boundary_tiles
-    bnd_rows = np.repeat(bnd, split.tile_rows, axis=1)[:, :L]
-    masks = (
-        jnp.ones(bnd.shape, np.int32),
-        jnp.ones((g, L), bool),
-        jnp.asarray(bnd.astype(np.int32)),
-        jnp.asarray(bnd_rows),
-    )
+    masks = op._masks if is_device else phase_masks(row_split(part), L, mesh)
     return TraceableOperator(
         topo=topo, local_size=L, overlap=True, use_pallas=use_pallas,
         mesh=mesh, exchange=None, remote=tx_remote, local=tx_local,

@@ -93,7 +93,6 @@ class DeviceReductions:
         from jax.sharding import PartitionSpec as P
 
         from repro.comm.strategies import _default_mesh
-        from repro.compat import shard_map
 
         self.topo = topo
         self.mesh = mesh if mesh is not None else _default_mesh(topo)
@@ -104,7 +103,7 @@ class DeviceReductions:
             return jnp.reshape(shard_dot(x, y), (1, 1))
 
         self._fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(P(WORLD_AXES), P(WORLD_AXES)),

@@ -8,8 +8,9 @@
 The exchange is an :class:`repro.comm.strategies.IrregularExchange` planned by
 the selected strategy; ``strategy="auto"`` asks the model-driven advisor
 (paper §4.6) to pick, with ``payload_width`` feeding the advisor's batched
-byte terms.  The local compute runs the Pallas blocked-ELL kernels
-(interpret mode on CPU) or their jnp oracles.
+byte terms.  The local compute runs the Pallas blocked-ELL kernels (compiled
+on a TPU, interpreted elsewhere; :mod:`repro.kernels.ops` decides) or their
+jnp oracles.
 
 Multi-vector products (``V: [nranks, L, k]``) are first-class: one exchange
 moves all ``k`` columns under the single cached plan and one fused blocked-ELL
@@ -60,15 +61,18 @@ from jax.sharding import PartitionSpec as P
 
 from repro.comm import strategies as comm_strategies
 from repro.comm.strategies import IrregularExchange
-from repro.compat import shard_map
-from repro.comm.topology import WORLD_AXES, PodTopology, make_exchange_mesh
+from repro.comm.topology import (
+    WORLD_AXES,
+    PodTopology,
+    make_exchange_mesh,
+    shard_ranks,
+)
 from repro.core.advisor import EXECUTABLE_STRATEGY, advise
 from repro.core.perfmodel import Strategy, Transport
 from repro.core.split_plan import RowPhaseSplit, split_rows
+from repro.kernels import ops
 from repro.kernels import ref as kref
-from repro.kernels.spmv_ell import TILE_R, TILE_R_MM
-from repro.kernels.spmv_ell import spmm_ell as spmm_ell_kernel
-from repro.kernels.spmv_ell import spmv_ell as spmv_ell_kernel
+from repro.kernels.spmv_ell import TILE_R
 from repro.sparse.matrices import CSRMatrix
 from repro.sparse.partition import SpmvPartition, partition_csr
 
@@ -108,12 +112,12 @@ def _compute_program(
         if width is None:
             def local(data, cols, x):
                 if use_pallas:
-                    return spmv_ell_kernel(data, cols, x, interpret=True)
+                    return ops.spmv_ell(data, cols, x)
                 return kref.spmv_ell(data, cols, x)
         else:
             def local(data, cols, x):
                 if use_pallas:
-                    return spmm_ell_kernel(data, cols, x, interpret=True)
+                    return ops.spmm_ell(data, cols, x)
                 return kref.spmm_ell(data, cols, x)
 
         def compute(v_local, halo, dd, dc, od, oc):
@@ -123,7 +127,7 @@ def _compute_program(
             return w[None]
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 compute,
                 mesh=mesh,
                 in_specs=(P(WORLD_AXES),) * 6,
@@ -162,19 +166,19 @@ def _phase_program(
         if width is None:
             def local(data, cols, x, tiles, rows):
                 if use_pallas:
-                    return spmv_ell_kernel(data, cols, x, interpret=True, tile_mask=tiles)
+                    return ops.spmv_ell(data, cols, x, tile_mask=tiles)
                 return kref.spmv_ell_masked(data, cols, x, rows)
         else:
             def local(data, cols, x, tiles, rows):
                 if use_pallas:
-                    return spmm_ell_kernel(data, cols, x, interpret=True, tile_mask=tiles)
+                    return ops.spmm_ell(data, cols, x, tile_mask=tiles)
                 return kref.spmm_ell_masked(data, cols, x, rows)
 
         def compute(x, data, cols, tiles, rows):
             return local(data[0], cols[0], x[0], tiles[0], rows[0])[None]
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 compute,
                 mesh=mesh,
                 in_specs=(P(WORLD_AXES),) * 5,
@@ -186,6 +190,35 @@ def _phase_program(
     return comm_strategies.compute_cached(
         _COMPUTE_CACHE, key, COMPUTE_CACHE_MAX, build
     )
+
+
+def row_split(part: SpmvPartition) -> RowPhaseSplit:
+    """Interior/boundary row split (the overlap enabler) at the kernels' row
+    tile.
+
+    Classification is *structural*: a row is boundary iff its off-rank ELL
+    row holds at least one stored entry (``off_row_nnz > 0``), so an
+    explicitly stored zero still counts as a halo dependency and the split
+    never depends on matrix values.
+    """
+    g, L = part.topo.nranks, part.rows_per_rank
+    return split_rows(part.off_row_nnz.reshape(g, L) > 0, TILE_R)
+
+
+def phase_masks(split: RowPhaseSplit, L: int, mesh: jax.sharding.Mesh) -> tuple:
+    """The overlapped passes' masks, placed on ``mesh``: the all-tiles pair
+    (the diag pass) and the boundary pair (the off pass), each as
+    (tile mask, tile-expanded row mask)."""
+    g, ntiles = split.boundary_tiles.shape
+    bnd = split.boundary_tiles
+    bnd_rows = np.repeat(bnd, split.tile_rows, axis=1)[:, :L]
+    masks = (
+        np.ones((g, ntiles), np.int32),
+        np.ones((g, L), bool),
+        bnd.astype(np.int32),
+        bnd_rows,
+    )
+    return tuple(shard_ranks(m, mesh) for m in masks)
 
 
 @dataclasses.dataclass
@@ -303,68 +336,35 @@ class DistributedSpMV:
         L = self.partition.rows_per_rank
         g = topo.nranks
 
-        diag_d = jnp.asarray(self.partition.diag.data.reshape(g, L, -1))
-        diag_c = jnp.asarray(self.partition.diag.cols.reshape(g, L, -1))
-        off_d = jnp.asarray(self.partition.off.data.reshape(g, L, -1))
-        off_c = jnp.asarray(self.partition.off.cols.reshape(g, L, -1))
-
-        self._fingerprint = self.partition.pattern.fingerprint()
+        part = self.partition
+        self._fingerprint = part.pattern.fingerprint()
         self._compute = _compute_program(
             self._fingerprint, self.mesh, self.use_pallas, None
         )
-        self._blocks = (diag_d, diag_c, off_d, off_c)
+        # each device holds only its rank's slice of the ELL blocks
+        self._blocks = tuple(
+            shard_ranks(a.reshape(g, L, -1), self.mesh)
+            for a in (part.diag.data, part.diag.cols, part.off.data, part.off.cols)
+        )
         # per-instance memo over the module LRU: matmat's hot path must not
         # re-derive the (fingerprint, k, mesh) key per call
         self._mm_programs: dict = {}
 
-        self._row_splits: dict = {}
+        self._split: Optional[RowPhaseSplit] = None
         if self.overlap:
-            self._masks_v = self._phase_masks(self.row_split, L)
-            self._masks_mm = self._phase_masks(self.row_split_mm, L)
+            self._masks = phase_masks(self.row_split, L, self.mesh)
             self._phase_fn = _phase_program(
                 self._fingerprint, self.mesh, self.use_pallas, None
             )
             self._mm_phase_programs: dict = {}
 
-    def _row_split(self, tile_rows: int) -> RowPhaseSplit:
-        """Interior/boundary row split (the overlap enabler), lazily built.
-
-        Classification is *structural*: a row is boundary iff its off-rank
-        ELL row holds at least one stored entry (``off_row_nnz > 0``), so an
-        explicitly stored zero still counts as a halo dependency and the
-        split never depends on matrix values.
-        """
-        split = self._row_splits.get(tile_rows)
-        if split is None:
-            g, L = self.partition.topo.nranks, self.partition.rows_per_rank
-            halo_dep = self.partition.off_row_nnz.reshape(g, L) > 0
-            split = self._row_splits[tile_rows] = split_rows(halo_dep, tile_rows)
-        return split
-
     @property
     def row_split(self) -> RowPhaseSplit:
-        """Row split at the SpMV kernel's tile size."""
-        return self._row_split(TILE_R)
-
-    @property
-    def row_split_mm(self) -> RowPhaseSplit:
-        """Row split at the SpMM kernel's tile size."""
-        return self._row_split(TILE_R_MM)
-
-    @staticmethod
-    def _phase_masks(split: RowPhaseSplit, L: int):
-        """Device arrays for one tile size: the all-tiles mask pair (the
-        diag pass) and the boundary mask pair (the off pass), each as
-        (tile mask, tile-expanded row mask)."""
-        g, ntiles = split.interior_tiles.shape
-        bnd = split.boundary_tiles
-        bnd_rows = np.repeat(bnd, split.tile_rows, axis=1)[:, :L]
-        return (
-            jnp.ones((g, ntiles), np.int32),
-            jnp.ones((g, L), bool),
-            jnp.asarray(bnd.astype(np.int32)),
-            jnp.asarray(bnd_rows),
-        )
+        """Interior/boundary row split at the kernels' row tile (lazily built);
+        see :func:`row_split`."""
+        if self._split is None:
+            self._split = row_split(self.partition)
+        return self._split
 
     # ------------------------------------------------------------------
     def __call__(self, v: jax.Array) -> jax.Array:
@@ -375,7 +375,7 @@ class DistributedSpMV:
         if not self.overlap:
             halo = self.exchange(v)
             return self._compute(v, halo, *self._blocks)
-        all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks_v
+        all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks
         handle = self.exchange.start(v)
         # the whole halo-independent diag block runs while the inter-pod
         # phase is in flight; only boundary tiles' off-block waits on it
@@ -411,7 +411,7 @@ class DistributedSpMV:
             fn = self._mm_phase_programs[k] = _phase_program(
                 self._fingerprint, self.mesh, self.use_pallas, k
             )
-        all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks_mm
+        all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks
         handle = self.exchange.start(V)
         w_diag = fn(V, *self._blocks[:2], all_tiles, all_rows)
         halo = handle.finish()

@@ -1,13 +1,9 @@
 """Test-support utilities vendored with the library (no external deps)."""
 
-from repro.testing.hypo import given, settings, st
 from repro.testing.traces import ARRIVAL_PATTERNS, make_trace, zipf_weights
 
 __all__ = [
     "ARRIVAL_PATTERNS",
-    "given",
     "make_trace",
-    "settings",
-    "st",
     "zipf_weights",
 ]

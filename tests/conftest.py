@@ -20,13 +20,15 @@ SRC = os.path.join(REPO, "src")
 
 
 def run_devices(code: str, devices: int = 8, timeout: int = 600) -> str:
-    """Run ``code`` in a subprocess with N forced host devices.
+    """Run ``code`` in a subprocess with N forced CPU host devices.
 
     Multi-device tests must not set ``--xla_force_host_platform_device_count``
     in this process (smoke tests and benches should see 1 device), so they
-    run in a child interpreter.
+    run in a child interpreter pinned to the CPU, which never reaches for an
+    accelerator this process may hold.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

@@ -13,7 +13,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.compression import Compressor
-from repro.compat import shard_map
 
 
 def _round_trip(x: jnp.ndarray):
@@ -29,7 +28,7 @@ def _round_trip(x: jnp.ndarray):
         return out[None], residual[None]
 
     fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=(P("pod"), P("pod")))
+        jax.shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=(P("pod"), P("pod")))
     )
     out, res = fn(x[None])
     return out[0], res[0]
@@ -107,7 +106,7 @@ def test_compress_scale_dtype_follows_payload():
         return q[None], scale[None]
 
     fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=(P("pod"), P("pod")))
+        jax.shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=(P("pod"), P("pod")))
     )
     for dtype in (jnp.float32, jnp.bfloat16, jnp.float16):
         q, scale = fn(jnp.ones((1, 8), dtype))

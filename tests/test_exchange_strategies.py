@@ -3,11 +3,8 @@ reference exchange (8-device subprocess), plus in-process plan properties."""
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm.exchange import execute_numpy, plan, random_pattern, simulate
 from repro.comm.fusion import fuse

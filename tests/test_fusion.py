@@ -9,11 +9,8 @@ bit-for-bit on all of them, for every strategy.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm import _legacy_planner as legacy
 from repro.comm.exchange import (

@@ -4,11 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.launch.hlo_analysis import analyze
 from repro.models.layers import attend_chunked, attend_dot, rmsnorm, rmsnorm_params, rope

@@ -11,11 +11,8 @@ a generator bypassing it would be caught here.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.sparse.matrices import GENERATORS, CSRMatrix, banded, _from_coo
 

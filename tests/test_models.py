@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import tree_flatten_with_path
 from repro.configs import ARCH_IDS, get_config
 from repro.models import LMModel
 
@@ -67,7 +66,7 @@ def test_arch_smoke_forward_and_train_step(arch):
     assert bool(jnp.isfinite(logits).all()), "NaN/inf in logits"
     loss, grads = jax.value_and_grad(lambda p: model.loss(p, batch))(params)
     assert np.isfinite(float(loss))
-    for path, g in tree_flatten_with_path(grads)[0]:
+    for path, g in jax.tree.flatten_with_path(grads)[0]:
         assert bool(jnp.isfinite(g).all()), f"NaN grad at {jax.tree_util.keystr(path)}"
 
 

@@ -6,11 +6,8 @@ real shard_map collectives in an 8-device subprocess.
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm.exchange import (
     execute_numpy,

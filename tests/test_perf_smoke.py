@@ -424,3 +424,48 @@ print("EXCHANGE PRESSURE OK", s.exchange_hits, s.exchange_misses, s.exchange_evi
 """,
         devices=4,
     )
+
+
+def test_compile_cache_goes_where_the_environment_says(subproc, monkeypatch, tmp_path):
+    # JAX reads JAX_COMPILATION_CACHE_DIR when it is imported, so the child
+    # gets it in its environment; the helper must then set no other directory
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    out = subproc(
+        """
+import os
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import use_compile_cache
+
+want = os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert use_compile_cache() == want
+assert jax.config.jax_compilation_cache_dir == want
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: x * 3 + 1)(jnp.ones(5)).block_until_ready()
+print("CACHE", sorted(os.listdir(want)))
+""",
+        devices=1,
+    )
+    assert "jit__lambda" in out
+    assert any(p.name.startswith("jit__lambda") for p in tmp_path.iterdir())
+
+
+def test_compile_cache_default_is_a_fixed_ignored_dir(monkeypatch):
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.CACHE_DIR == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.use_compile_cache() == compile_cache.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()

@@ -15,11 +15,8 @@ binning regression and the >= 3x coalescing-throughput acceptance pin.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm import PodTopology, random_pattern
 from repro.runtime import AdmissionController, StragglerWatchdog

@@ -18,11 +18,8 @@ Three layers of guarantees:
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm import strategies as comm_strategies
 from repro.comm.topology import PodTopology

@@ -10,11 +10,8 @@ for the per-column loop.
 import numpy as np
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ref
 from repro.kernels.spmv_ell import spmm_ell, spmv_ell
@@ -78,8 +75,10 @@ def test_spmm_ell_property(r, k, n, c, seed):
 )
 @settings(max_examples=15, deadline=None)
 def test_spmm_k1_degenerates_to_spmv_exactly(r, k, n, seed):
-    """A single-column rhs must reproduce the SpMV kernel bit-for-bit: same
-    K padding, same reduction order, one degenerate column tile."""
+    """Interpreted, a single-column rhs reproduces the SpMV kernel bit for
+    bit (one degenerate column tile, the same reduce over K).  The match is
+    pinned in interpret mode only: compiled, the two kernels reduce along
+    different axes and may differ in the last bit."""
     rng = np.random.default_rng(seed)
     data = rng.normal(size=(r, k)).astype(np.float32)
     cols = rng.integers(0, n, size=(r, k)).astype(np.int32)

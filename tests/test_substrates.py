@@ -7,11 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.checkpoint import CheckpointManager, load_checkpoint, save_checkpoint
 from repro.data import SyntheticTokens

@@ -117,14 +117,13 @@ def test_hierarchical_collectives_and_compression(subproc):
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.comm import (psum_hierarchical, psum_flat, all_to_all_hierarchical, Compressor)
-from repro.compat import shard_map
 
 mesh = jax.make_mesh((2, 4), ("pod", "data"))
 x = np.random.default_rng(0).normal(size=(8, 5, 3)).astype(np.float32)
 
 def body(v):
     return psum_hierarchical(v, "pod", "data"), psum_flat(v, "pod", "data")
-f = jax.jit(shard_map(body, mesh=mesh, in_specs=P(("pod", "data")),
+f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(("pod", "data")),
                           out_specs=(P(("pod", "data")), P(("pod", "data")))))
 a, b = f(x)
 np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
@@ -132,7 +131,7 @@ np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 def body2(v):
     return (all_to_all_hierarchical(v, "pod", "data"),
             jax.lax.all_to_all(v, ("pod", "data"), 0, 0, tiled=True))
-g = jax.jit(shard_map(body2, mesh=mesh, in_specs=P(("pod", "data")),
+g = jax.jit(jax.shard_map(body2, mesh=mesh, in_specs=P(("pod", "data")),
                           out_specs=(P(("pod", "data")), P(("pod", "data")))))
 y, z = g(np.arange(64.0, dtype=np.float32).reshape(64, 1))
 np.testing.assert_allclose(np.asarray(y), np.asarray(z))
@@ -140,7 +139,7 @@ np.testing.assert_allclose(np.asarray(y), np.asarray(z))
 comp = Compressor()
 def body3(v, r):
     return psum_hierarchical(v, "pod", "data", comp, r)
-h = jax.jit(shard_map(body3, mesh=mesh,
+h = jax.jit(jax.shard_map(body3, mesh=mesh,
                           in_specs=(P(("pod", "data")), P(("pod", "data"))),
                           out_specs=(P(("pod", "data")), P(("pod", "data")))))
 xs = np.random.default_rng(1).normal(size=(8, 16)).astype(np.float32)

@@ -11,11 +11,8 @@ and the reported ``wire_bytes`` show the inter-pod byte reduction.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # CI image has no hypothesis; use the vendored shim
-    from repro.testing.hypo import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm import wire
 from repro.comm.exchange import (
