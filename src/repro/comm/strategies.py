@@ -66,6 +66,7 @@ from repro.comm.topology import (
     make_exchange_mesh,
     shard_ranks,
 )
+from repro.trace import scope, span
 
 # ---------------------------------------------------------------------------
 # Compiled-program representation (ext-once execution)
@@ -199,6 +200,10 @@ def _execute(
     shard's world rank and applied to the decoded receive blocks, mirroring
     :func:`repro.comm.exchange.execute_numpy` bitwise.
 
+    Each op runs under the device scope ``exchange.<kind>`` (``gather``,
+    ``a2a_local``, ``a2a_pod``, ``permute``), the codec's encode and decode
+    under ``exchange.codec``; callers wrap the whole in ``exchange``.
+
     Returns ``(out [1, out_size, *feat], viols)`` where ``viols`` is a list
     of per-hop violation scalars (empty unless ``verify``).
     """
@@ -213,96 +218,101 @@ def _execute(
     ai = 0
     for op_i, op in enumerate(ops):
         kind = op[0]
-        if kind == "gather":
-            _, width = op
-            idx = plan_arrays[ai][0]
-            ai += 1
-            vals = ext.at[idx].get(mode="fill", fill_value=0)
-            ext = ext.at[L : L + width].set(vals)
-        elif kind in ("a2a_local", "a2a_pod"):
-            _, buflen, has_idx = op
-            if has_idx:
+        with scope("exchange." + kind):
+            if kind == "gather":
+                _, width = op
                 idx = plan_arrays[ai][0]
                 ai += 1
-                seg = ext.at[idx].get(mode="fill", fill_value=0)
-            else:
-                seg = ext[L : L + buflen]
-            groups, axis = (
-                (topo.ppn, LOCAL_AXIS)
-                if kind == "a2a_local"
-                else (topo.npods, POD_AXIS)
-            )
-            blocks = seg.reshape((groups, buflen // groups) + feat)
-            check = verify and kind == "a2a_pod"
-            if check:
-                chk = _wire_check(blocks, tuple(range(1, blocks.ndim)))
-                chk_moved = jax.lax.all_to_all(chk, axis, 0, 0, tiled=True)
-            if kind == "a2a_pod" and encode:
-                payload, aux = _encode_blocks(blocks, codec)
-                moved = jax.lax.all_to_all(payload, axis, 0, 0, tiled=True)
-                if aux is not None:
-                    aux = jax.lax.all_to_all(aux, axis, 0, 0, tiled=True)
-                res = _decode_blocks(moved, aux, x.dtype)
-                # the own-pod block never crossed DCI: the all_to_all self
-                # slot holds this rank's own send block, so restore it at
-                # full precision
-                me = jax.lax.axis_index(axis)
-                keep = (jnp.arange(groups) == me).reshape(
-                    (groups,) + (1,) * (blocks.ndim - 1)
-                )
-                res = jnp.where(keep, blocks, res)
-            else:
-                res = jax.lax.all_to_all(blocks, axis, 0, 0, tiled=True)
-            if kind == "a2a_pod" and fault_ops:
-                for fkind, mask, value in fault_ops.get((op_i, None), ()):
-                    res = _apply_injection(res, mask[rank], fkind, value)
-            if check:
-                chk_post = _wire_check(res, tuple(range(1, res.ndim)))
-                nelem = int(np.prod(blocks.shape[1:], dtype=np.int64))
-                viols.append(
-                    _check_violation(chk_moved, chk_post, nelem, codec, encode)
-                )
-            ext = ext.at[L : L + buflen].set(res.reshape((buflen,) + feat))
-        elif kind == "permute":
-            _, rounds, blks, inters = op
-            parts = []
-            for ri, (perm, blk, inter) in enumerate(zip(rounds, blks, inters)):
-                sel = plan_arrays[ai][0]
-                ai += 1
-                send = ext.at[sel].get(mode="fill", fill_value=0)
-                if not perm:
-                    parts.append(jnp.zeros_like(send))
-                    continue
-                check = verify and inter
-                if check:
-                    chk = _wire_check(send, tuple(range(send.ndim)))
-                    chk_moved = jax.lax.ppermute(chk, WORLD_AXES, list(perm))
-                if inter and encode:
-                    payload, aux = _encode_blocks(send[None], codec)
-                    moved = jax.lax.ppermute(payload[0], WORLD_AXES, list(perm))
-                    if aux is not None:
-                        aux = jax.lax.ppermute(aux[0], WORLD_AXES, list(perm))
-                        aux = aux[None]
-                    part = _decode_blocks(moved[None], aux, x.dtype)[0]
+                vals = ext.at[idx].get(mode="fill", fill_value=0)
+                ext = ext.at[L : L + width].set(vals)
+            elif kind in ("a2a_local", "a2a_pod"):
+                _, buflen, has_idx = op
+                if has_idx:
+                    idx = plan_arrays[ai][0]
+                    ai += 1
+                    seg = ext.at[idx].get(mode="fill", fill_value=0)
                 else:
-                    part = jax.lax.ppermute(send, WORLD_AXES, list(perm))
-                if fault_ops:
-                    for fkind, mask, value in fault_ops.get((op_i, ri), ()):
-                        part = _apply_injection(part, mask[rank], fkind, value)
+                    seg = ext[L : L + buflen]
+                groups, axis = (
+                    (topo.ppn, LOCAL_AXIS)
+                    if kind == "a2a_local"
+                    else (topo.npods, POD_AXIS)
+                )
+                blocks = seg.reshape((groups, buflen // groups) + feat)
+                check = verify and kind == "a2a_pod"
                 if check:
-                    chk_post = _wire_check(part, tuple(range(part.ndim)))
-                    nelem = int(np.prod(send.shape, dtype=np.int64))
-                    viols.append(
-                        _check_violation(
-                            chk_moved, chk_post, nelem, codec, inter and encode
-                        )
+                    chk = _wire_check(blocks, tuple(range(1, blocks.ndim)))
+                    chk_moved = jax.lax.all_to_all(chk, axis, 0, 0, tiled=True)
+                if kind == "a2a_pod" and encode:
+                    with scope("exchange.codec"):
+                        payload, aux = _encode_blocks(blocks, codec)
+                    moved = jax.lax.all_to_all(payload, axis, 0, 0, tiled=True)
+                    if aux is not None:
+                        aux = jax.lax.all_to_all(aux, axis, 0, 0, tiled=True)
+                    with scope("exchange.codec"):
+                        res = _decode_blocks(moved, aux, x.dtype)
+                    # the own-pod block never crossed DCI: the all_to_all self
+                    # slot holds this rank's own send block, so restore it at
+                    # full precision
+                    me = jax.lax.axis_index(axis)
+                    keep = (jnp.arange(groups) == me).reshape(
+                        (groups,) + (1,) * (blocks.ndim - 1)
                     )
-                parts.append(part)
-            width = sum(blks)
-            if parts:
-                ext = ext.at[L : L + width].set(jnp.concatenate(parts))
-        else:
-            raise TypeError(f"unknown op {op!r}")
+                    res = jnp.where(keep, blocks, res)
+                else:
+                    res = jax.lax.all_to_all(blocks, axis, 0, 0, tiled=True)
+                if kind == "a2a_pod" and fault_ops:
+                    for fkind, mask, value in fault_ops.get((op_i, None), ()):
+                        res = _apply_injection(res, mask[rank], fkind, value)
+                if check:
+                    chk_post = _wire_check(res, tuple(range(1, res.ndim)))
+                    nelem = int(np.prod(blocks.shape[1:], dtype=np.int64))
+                    viols.append(
+                        _check_violation(chk_moved, chk_post, nelem, codec, encode)
+                    )
+                ext = ext.at[L : L + buflen].set(res.reshape((buflen,) + feat))
+            elif kind == "permute":
+                _, rounds, blks, inters = op
+                parts = []
+                for ri, (perm, blk, inter) in enumerate(zip(rounds, blks, inters)):
+                    sel = plan_arrays[ai][0]
+                    ai += 1
+                    send = ext.at[sel].get(mode="fill", fill_value=0)
+                    if not perm:
+                        parts.append(jnp.zeros_like(send))
+                        continue
+                    check = verify and inter
+                    if check:
+                        chk = _wire_check(send, tuple(range(send.ndim)))
+                        chk_moved = jax.lax.ppermute(chk, WORLD_AXES, list(perm))
+                    if inter and encode:
+                        with scope("exchange.codec"):
+                            payload, aux = _encode_blocks(send[None], codec)
+                        moved = jax.lax.ppermute(payload[0], WORLD_AXES, list(perm))
+                        if aux is not None:
+                            aux = jax.lax.ppermute(aux[0], WORLD_AXES, list(perm))
+                            aux = aux[None]
+                        with scope("exchange.codec"):
+                            part = _decode_blocks(moved[None], aux, x.dtype)[0]
+                    else:
+                        part = jax.lax.ppermute(send, WORLD_AXES, list(perm))
+                    if fault_ops:
+                        for fkind, mask, value in fault_ops.get((op_i, ri), ()):
+                            part = _apply_injection(part, mask[rank], fkind, value)
+                    if check:
+                        chk_post = _wire_check(part, tuple(range(part.ndim)))
+                        nelem = int(np.prod(send.shape, dtype=np.int64))
+                        viols.append(
+                            _check_violation(
+                                chk_moved, chk_post, nelem, codec, inter and encode
+                            )
+                        )
+                    parts.append(part)
+                width = sum(blks)
+                if parts:
+                    ext = ext.at[L : L + width].set(jnp.concatenate(parts))
+            else:
+                raise TypeError(f"unknown op {op!r}")
     return ext[L : L + out_size][None], viols
 
 
@@ -366,11 +376,12 @@ class TraceableExchange:
         Runs inside ``shard_map`` (directly or nested in a traced loop);
         ``plan_arrays`` are the per-shard slices of :attr:`plan_arrays`.
         """
-        out, _ = _execute(
-            self.lowered.ops, self.topo, self.lowered.local_size,
-            self.lowered.w_max, self.lowered.out_size, local, plan_arrays,
-            self.codec, verify=False, fault_ops=self.fault_ops,
-        )
+        with scope("exchange"):
+            out, _ = _execute(
+                self.lowered.ops, self.topo, self.lowered.local_size,
+                self.lowered.w_max, self.lowered.out_size, local, plan_arrays,
+                self.codec, verify=False, fault_ops=self.fault_ops,
+            )
         return out
 
     def run_verified(self, local, *plan_arrays):
@@ -378,11 +389,12 @@ class TraceableExchange:
 
         With :attr:`emit_checks` False the violation vector is empty.
         """
-        out, viols = _execute(
-            self.lowered.ops, self.topo, self.lowered.local_size,
-            self.lowered.w_max, self.lowered.out_size, local, plan_arrays,
-            self.codec, verify=self.emit_checks, fault_ops=self.fault_ops,
-        )
+        with scope("exchange"):
+            out, viols = _execute(
+                self.lowered.ops, self.topo, self.lowered.local_size,
+                self.lowered.w_max, self.lowered.out_size, local, plan_arrays,
+                self.codec, verify=self.emit_checks, fault_ops=self.fault_ops,
+            )
         if viols:
             return out, jnp.stack(viols)
         return out, jnp.zeros((0,), jnp.float32)
@@ -505,7 +517,7 @@ _MESH_CACHE: "OrderedDict[tuple, jax.sharding.Mesh]" = OrderedDict()
 _SPLIT_CACHE: "OrderedDict[str, tuple]" = OrderedDict()
 #: constructed IrregularExchange instances (per-batch dynamic-pattern callers)
 _EXCHANGE_CACHE: "OrderedDict[tuple, IrregularExchange]" = OrderedDict()
-#: fused whole-solve programs (jitted fn + device operands), keyed by
+#: fused whole-solve programs (jitted fns), keyed by
 #: (fingerprint, solver, strategy, codec, overlap, dtype, ...) tuples built
 #: by repro.solve.fused
 _FUSED_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
@@ -640,11 +652,12 @@ def compute_cached(cache: OrderedDict, key, max_size: int, build):
 def fused_cached(key, build):
     """LRU get for the fused whole-solve program cache.
 
-    ``build()`` returns the cached value (jitted solve fn + device operands
-    + exchange metadata); hits and misses land under ``fused_hits`` /
-    ``fused_misses`` and trims under ``fused_evictions``, so fused programs
-    participate in the same cache-pressure machinery (:func:`cache_sizes`,
-    :func:`set_cache_limits`) as every other compiled artifact.
+    ``build()`` returns the cached value (the jitted solve program; each
+    caller passes its own operands); hits and misses land under
+    ``fused_hits`` / ``fused_misses`` and trims under ``fused_evictions``,
+    so fused programs participate in the same cache-pressure machinery
+    (:func:`cache_sizes`, :func:`set_cache_limits`) as every other compiled
+    artifact.
     """
     val, hit = _lru_get(_FUSED_CACHE, key, FUSED_CACHE_MAX, build, "fused_evictions")
     if hit:
@@ -797,11 +810,12 @@ def merge_shard(mask, valid, li, ri, local_out, remote_out):
         idx = jnp.broadcast_to(idx, idx.shape[:2] + buf.shape[2:])
         return jnp.take_along_axis(buf, idx, axis=1)
 
-    m = mask.reshape(mask.shape + (1,) * nfeat)
-    v = valid.reshape(valid.shape + (1,) * nfeat)
-    lo = take(local_out, li)
-    merged = jnp.where(m, lo, take(remote_out, ri))
-    return jnp.where(v, merged, jnp.zeros_like(lo))
+    with scope("exchange"):
+        m = mask.reshape(mask.shape + (1,) * nfeat)
+        v = valid.reshape(valid.shape + (1,) * nfeat)
+        lo = take(local_out, li)
+        merged = jnp.where(m, lo, take(remote_out, ri))
+        return jnp.where(v, merged, jnp.zeros_like(lo))
 
 
 def _build_merge(sp: SplitPhase, mesh: jax.sharding.Mesh):
@@ -1022,9 +1036,10 @@ class IrregularExchange:
             raise ValueError(
                 f"expected [{n}, {L}, *feat], got {tuple(local.shape)}"
             )
-        if self.faults is None and not self.verify:
-            return self._fn(local, *self._arrays)
-        return self._guarded_call(local)
+        with span("exchange"):
+            if self.faults is None and not self.verify:
+                return self._fn(local, *self._arrays)
+            return self._guarded_call(local)
 
     # -- verification + recovery ---------------------------------------
     def _raw_call(self, local: jax.Array, call_index: int) -> jax.Array:

@@ -47,6 +47,10 @@ Row-tile masking (the split-phase/overlap hook):
 
 Both kernels take ``interpret`` without a default: the main path reaches
 them through :mod:`repro.kernels.ops`, which picks the mode from the backend.
+
+Device scopes (:func:`repro.trace.scope`): ``spmv.layout`` on the
+transposes, pads and output slice, ``spmv.gather`` on the XLA gather,
+``spmv.kernel`` on the ``pallas_call``.
 """
 
 from __future__ import annotations
@@ -57,6 +61,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.trace import scope
 
 TILE_R = 2048  # rows per grid step (lanes)
 TILE_C = 8  # rhs columns per SpMM grid step (one sublane tile)
@@ -149,19 +155,23 @@ def spmv_ell(
     gathered).
     """
     R = data.shape[0]
-    data_t, cols_t = _transpose_blocks(data, cols)  # [K, Rp]
-    g = x[cols_t]
-    out = _ell_call(
-        data_t,
-        g,
-        grid=(num_row_tiles(R),),
-        g_spec=pl.BlockSpec((data_t.shape[0], TILE_R), lambda i, *_: (0, i)),
-        out_spec=pl.BlockSpec((TILE_R,), lambda i, *_: (i,)),
-        out_shape=(data_t.shape[1],),
-        tile_mask=tile_mask,
-        interpret=interpret,
-    )
-    return out[:R]
+    with scope("spmv.layout"):
+        data_t, cols_t = _transpose_blocks(data, cols)  # [K, Rp]
+    with scope("spmv.gather"):
+        g = x[cols_t]
+    with scope("spmv.kernel"):
+        out = _ell_call(
+            data_t,
+            g,
+            grid=(num_row_tiles(R),),
+            g_spec=pl.BlockSpec((data_t.shape[0], TILE_R), lambda i, *_: (0, i)),
+            out_spec=pl.BlockSpec((TILE_R,), lambda i, *_: (i,)),
+            out_shape=(data_t.shape[1],),
+            tile_mask=tile_mask,
+            interpret=interpret,
+        )
+    with scope("spmv.layout"):
+        return out[:R]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -182,18 +192,22 @@ def spmm_ell(
     R = data.shape[0]
     C = x.shape[1]
     tc = min(C, TILE_C)
-    x_t = _pad_to(x, tc, 1).T  # [Cp, N]
-    data_t, cols_t = _transpose_blocks(data, cols)  # [K, Rp]
-    g = jnp.moveaxis(x_t[:, cols_t], 0, 1)  # [K, Cp, Rp]
+    with scope("spmv.layout"):
+        x_t = _pad_to(x, tc, 1).T  # [Cp, N]
+        data_t, cols_t = _transpose_blocks(data, cols)  # [K, Rp]
+    with scope("spmv.gather"):
+        g = jnp.moveaxis(x_t[:, cols_t], 0, 1)  # [K, Cp, Rp]
     K, Cp, Rp = g.shape
-    out = _ell_call(
-        data_t,
-        g,
-        grid=(num_row_tiles(R), Cp // tc),
-        g_spec=pl.BlockSpec((K, tc, TILE_R), lambda i, c, *_: (0, c, i)),
-        out_spec=pl.BlockSpec((tc, TILE_R), lambda i, c, *_: (c, i)),
-        out_shape=(Cp, Rp),
-        tile_mask=tile_mask,
-        interpret=interpret,
-    )
-    return out[:C, :R].T
+    with scope("spmv.kernel"):
+        out = _ell_call(
+            data_t,
+            g,
+            grid=(num_row_tiles(R), Cp // tc),
+            g_spec=pl.BlockSpec((K, tc, TILE_R), lambda i, c, *_: (0, c, i)),
+            out_spec=pl.BlockSpec((tc, TILE_R), lambda i, c, *_: (c, i)),
+            out_shape=(Cp, Rp),
+            tile_mask=tile_mask,
+            interpret=interpret,
+        )
+    with scope("spmv.layout"):
+        return out[:C, :R].T

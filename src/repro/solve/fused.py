@@ -67,6 +67,7 @@ from repro.solve.krylov import (
 )
 from repro.solve.operator import traceable_operator
 from repro.solve.reductions import traceable_dot
+from repro.trace import scope, span
 
 # status codes carried through the loop (mapped back to the host solvers'
 # status strings on exit)
@@ -232,6 +233,16 @@ def _bicgstab_body(mv, dot, tol, bnorm, rhat, rhat_nrm, eps, hist_len):
     return body
 
 
+def _in_scope(name: str, fn):
+    """``fn`` with the operations it traces under device scope ``name``."""
+
+    def scoped(*args):
+        with scope(name):
+            return fn(*args)
+
+    return scoped
+
+
 def _build_fused(top, shard_dot, solver: str, hist_len: int, eps: float,
                  nviol: int, checkpoint_every: Optional[int] = None,
                  gate=None, resume: bool = False):
@@ -335,8 +346,10 @@ def _build_fused(top, shard_dot, solver: str, hist_len: int, eps: float,
 
         (carry, bnorm, rhat, rhat_nrm, fdt) = carry_parts(mv, dot, tol)
 
+        # the vector updates; the matvec and the dots inside carry their
+        # own scopes (spmv.*, exchange.*, solve.reduce)
         if solver == "cg":
-            body = _cg_body(mv, dot, tol, bnorm, hist_len)
+            body = _in_scope("solve.update", _cg_body(mv, dot, tol, bnorm, hist_len))
             best_x_idx, it_idx = 5, 7
             k_idx, st_idx, done_idx, mv_idx, viol_idx = 8, 10, 11, 12, 13
 
@@ -349,10 +362,10 @@ def _build_fused(top, shard_dot, solver: str, hist_len: int, eps: float,
                 )[None].astype(jnp.int32)
                 return ck_vec, ck_f, ck_i, c[9][None]
         else:
-            body = _bicgstab_body(
+            body = _in_scope("solve.update", _bicgstab_body(
                 mv, dot, tol, bnorm, rhat, rhat_nrm,
                 jnp.asarray(eps, fdt), hist_len,
-            )
+            ))
             best_x_idx, it_idx = 9, 11
             k_idx, st_idx, done_idx, mv_idx, viol_idx = 12, 14, 15, 16, 17
 
@@ -473,14 +486,16 @@ def _build_fused(top, shard_dot, solver: str, hist_len: int, eps: float,
 def _fused_entry(op, solver: str, maxiter: int, dtype, compressor,
                  checkpoint_every: Optional[int] = None,
                  resume: bool = False):
-    """Fetch (or build) the compiled whole-solve program for ``op``.
+    """Fetch (or build) the compiled whole-solve program for ``op``; return
+    it with ``op``'s own lowering.
 
-    The key is derived from the operator's configuration alone -- the
-    expensive lowering (:func:`traceable_operator`: device transfer of plan
-    arrays, blocks, masks) runs only on a miss.  ``resume=True`` fetches
-    the checkpoint-resume companion entry point (requires
-    ``checkpoint_every``); the two share a key prefix but compile
-    separately.
+    The key is derived from the operator's configuration and sparsity
+    alone, so every operator that shares them shares one compiled program.
+    The operands it runs on (device blocks, plan arrays, masks) are always
+    the calling operator's: :func:`_lowered` lowers each operator instance
+    once.  ``resume=True`` fetches the checkpoint-resume companion entry
+    point (requires ``checkpoint_every``); the two share a key prefix but
+    compile separately.
     """
     faults = getattr(op, "faults", None)
     mesh = getattr(op, "mesh", None)
@@ -495,8 +510,9 @@ def _fused_entry(op, solver: str, maxiter: int, dtype, compressor,
         checkpoint_every, "resume" if resume else "fwd",
     )
 
+    top = _lowered(op)
+
     def build():
-        top = traceable_operator(op)
         gate = None
         if faults is not None and faults.active_calls is not None:
             # call-indexed fault schedule: trace BOTH lowerings and select
@@ -509,12 +525,19 @@ def _fused_entry(op, solver: str, maxiter: int, dtype, compressor,
         nviol = len(top.verifier.checks) if top.verifier is not None else 1
         eps = float(np.finfo(dtype).eps)
         hist_len = int(maxiter) + 1
-        fn = _build_fused(top, shard_dot, solver, hist_len, eps, nviol,
-                          checkpoint_every=checkpoint_every, gate=gate,
-                          resume=resume)
-        return fn, top
+        return _build_fused(top, shard_dot, solver, hist_len, eps, nviol,
+                            checkpoint_every=checkpoint_every, gate=gate,
+                            resume=resume)
 
-    return comm_strategies.fused_cached(key, build)
+    return comm_strategies.fused_cached(key, build), top
+
+
+def _lowered(op):
+    """:func:`traceable_operator` of ``op``, once per operator instance."""
+    top = op.__dict__.get("_fused_lowering")
+    if top is None:
+        top = op._fused_lowering = traceable_operator(op)
+    return top
 
 
 def _limits(top, tol: float, max_it: int, dtype) -> tuple:
@@ -527,19 +550,21 @@ def _limits(top, tol: float, max_it: int, dtype) -> tuple:
 
 
 def _dispatch(fn, top, b_dev, x0_dev, tol: float, max_it: int, dtype):
-    outs = _raw_forward(fn, top, b_dev, x0_dev, tol, max_it, dtype)
+    with span("solve.loop"):
+        outs = _raw_forward(fn, top, b_dev, x0_dev, tol, max_it, dtype)
     x, best_x, hist, it, k, status, mvc, viols = outs[:8]
-    if top.verifier is not None:
-        top.verifier.raise_viols(np.asarray(viols))
-    k = int(np.asarray(k)[0, 0])
-    return (
-        x,
-        best_x,
-        [float(h) for h in np.asarray(hist)[0, :k]],
-        int(np.asarray(it)[0, 0]),
-        int(np.asarray(status)[0, 0]),
-        int(np.asarray(mvc)[0, 0]),
-    )
+    with span("solve.readback"):
+        if top.verifier is not None:
+            top.verifier.raise_viols(np.asarray(viols))
+        k = int(np.asarray(k)[0, 0])
+        return (
+            x,
+            best_x,
+            [float(h) for h in np.asarray(hist)[0, :k]],
+            int(np.asarray(it)[0, 0]),
+            int(np.asarray(status)[0, 0]),
+            int(np.asarray(mvc)[0, 0]),
+        )
 
 
 class _Checkpoint(NamedTuple):
@@ -622,10 +647,11 @@ def _fused_solve(op, b, x0, tol: float, maxiter: int, reductions,
                            status=_finish_status("converged", 0, op, rc0))
     dtype = b.dtype
     mesh = getattr(op, "mesh", None) or comm_strategies._default_mesh(op.topo)
-    b_dev = shard_ranks(b, mesh)
-    x0_dev = shard_ranks(
-        np.zeros_like(b) if x0 is None else np.asarray(x0, dtype=dtype), mesh
-    )
+    with span("solve.upload"):
+        b_dev = shard_ranks(b, mesh)
+        x0_dev = shard_ranks(
+            np.zeros_like(b) if x0 is None else np.asarray(x0, dtype=dtype), mesh
+        )
     # the program always runs the init matvec (for x0=0 it computes
     # b - A@0 = b exactly); the host loops only count it when x0 is given
     init_mv_adjust = 1 if x0 is None else 0
@@ -668,8 +694,10 @@ def _fused_solve(op, b, x0, tol: float, maxiter: int, reductions,
         status_str = _STATUS_STR[status]
         converged = status == _CONV
 
+    with span("solve.download"):
+        x = np.asarray(x)
     return SolveResult(
-        x=np.asarray(x),
+        x=x,
         converged=converged,
         iterations=it,
         residuals=tuple(hist),

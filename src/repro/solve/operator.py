@@ -35,6 +35,7 @@ from repro.comm import wire as wire_mod
 from repro.comm.exchange import execute_numpy, merge_split_phase
 from repro.comm.topology import PodTopology
 from repro.sparse.partition import SpmvPartition
+from repro.trace import scope
 
 
 def _ell_matvec(data: np.ndarray, cols: np.ndarray, x: np.ndarray) -> np.ndarray:
@@ -279,7 +280,8 @@ class TraceableOperator:
     # ------------------------------------------------------------------
     def matvec(self, v, *operands):
         """Pure per-shard matvec: ``v [1, L] -> w [1, L]``."""
-        w, _ = self._apply(v, operands, verified=False)
+        with scope("spmv"):
+            w, _ = self._apply(v, operands, verified=False)
         return w
 
     def matvec_verified(self, v, *operands):
@@ -287,15 +289,19 @@ class TraceableOperator:
         integrity violation vector of the DCI-crossing exchange (empty when
         nothing is checked); surface positives via
         ``self.verifier.raise_viols``."""
-        return self._apply(v, operands, verified=True)
+        with scope("spmv"):
+            return self._apply(v, operands, verified=True)
 
     def _apply(self, v, operands, verified: bool):
         k = self.n_exchange_ops
         if not self.overlap:
             pa, (dd, dc, od, oc) = operands[:k], operands[k:]
             halo, viols = self._run_exchange(self.exchange, v, pa, verified)
-            w = self._full(dd[0], dc[0], v[0]) + self._full(od[0], oc[0], halo[0])
-            return w[None], viols
+            with scope("spmv.diag"):
+                w_diag = self._full(dd[0], dc[0], v[0])
+            with scope("spmv.off"):
+                w_off = self._full(od[0], oc[0], halo[0])
+            return (w_diag + w_off)[None], viols
         rpa = operands[:k]
         lpa = operands[k : k + self.n_local_ops]
         (
@@ -311,9 +317,11 @@ class TraceableOperator:
         halo = comm_strategies.merge_shard(
             mask, valid, li, ri, local_out, remote_out
         )
-        w = self._masked(dd[0], dc[0], v[0], all_tiles[0], all_rows[0])
-        w = w + self._masked(od[0], oc[0], halo[0], bnd_tiles[0], bnd_rows[0])
-        return w[None], viols
+        with scope("spmv.diag"):
+            w_diag = self._masked(dd[0], dc[0], v[0], all_tiles[0], all_rows[0])
+        with scope("spmv.off"):
+            w_off = self._masked(od[0], oc[0], halo[0], bnd_tiles[0], bnd_rows[0])
+        return (w_diag + w_off)[None], viols
 
     @staticmethod
     def _run_exchange(tx, v, plan_arrays, verified: bool):

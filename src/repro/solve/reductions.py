@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.comm import compression
 from repro.comm.topology import LOCAL_AXIS, POD_AXIS, WORLD_AXES, PodTopology
+from repro.trace import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,7 +65,8 @@ def traceable_dot(compressor: Optional[compression.Compressor] = None):
     from repro.comm.hierarchical import dot_hierarchical
 
     def dot(x, y):
-        return dot_hierarchical(x[0], y[0], POD_AXIS, LOCAL_AXIS, compressor)
+        with scope("solve.reduce"):
+            return dot_hierarchical(x[0], y[0], POD_AXIS, LOCAL_AXIS, compressor)
 
     return dot
 

@@ -23,6 +23,7 @@ import numpy as np
 from repro.comm.exchange import ExchangePattern, Need
 from repro.comm.topology import PodTopology
 from repro.sparse.matrices import CSRMatrix
+from repro.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,7 +59,16 @@ class SpmvPartition:
 
 
 def partition_csr(matrix: CSRMatrix, topo: PodTopology) -> SpmvPartition:
-    """Partition ``matrix`` row-wise over ``topo.nranks`` ranks."""
+    """Partition ``matrix`` row-wise over ``topo.nranks`` ranks.
+
+    Host span ``repro.partition``, with one child span per host loop:
+    ``.needs``, ``.pattern``, ``.widths``, ``.fill``.
+    """
+    with span("partition"):
+        return _partition_csr(matrix, topo)
+
+
+def _partition_csr(matrix: CSRMatrix, topo: PodTopology) -> SpmvPartition:
     g = topo.nranks
     if matrix.n % g:
         raise ValueError(f"matrix dim {matrix.n} not divisible by {g} ranks")
@@ -67,57 +77,63 @@ def partition_csr(matrix: CSRMatrix, topo: PodTopology) -> SpmvPartition:
     def owner(col: int) -> int:
         return col // L
 
-    # 1. per-rank column dependencies -> exchange pattern
-    needs_by_pair: Dict[Tuple[int, int], set] = defaultdict(set)
-    for r in range(g):
-        for i in range(r * L, (r + 1) * L):
-            cols, _ = matrix.row(i)
-            for c in cols:
-                o = owner(int(c))
-                if o != r:
-                    needs_by_pair[(r, o)].add(int(c) - o * L)
-    needs = tuple(
-        Need(dst=dst, src=src, idx=tuple(sorted(elems)))
-        for (dst, src), elems in sorted(needs_by_pair.items())
-    )
-    pattern = ExchangePattern(topo=topo, local_size=L, needs=needs)
+    # 1. per-rank column dependencies
+    with span("partition.needs"):
+        needs_by_pair: Dict[Tuple[int, int], set] = defaultdict(set)
+        for r in range(g):
+            for i in range(r * L, (r + 1) * L):
+                cols, _ = matrix.row(i)
+                for c in cols:
+                    o = owner(int(c))
+                    if o != r:
+                        needs_by_pair[(r, o)].add(int(c) - o * L)
+        needs = tuple(
+            Need(dst=dst, src=src, idx=tuple(sorted(elems)))
+            for (dst, src), elems in sorted(needs_by_pair.items())
+        )
 
-    # 2. canonical halo layout: position of (owner, elem) in dst's recv buffer
-    halo_pos: List[Dict[Tuple[int, int], int]] = []
-    for r in range(g):
-        pos = {tok: k for k, tok in enumerate(pattern.canonical_tokens(r))}
-        halo_pos.append(pos)
-    H = max(pattern.max_recv_size(), 1)
+    # 2. exchange pattern and canonical halo layout: position of (owner,
+    # elem) in dst's recv buffer
+    with span("partition.pattern"):
+        pattern = ExchangePattern(topo=topo, local_size=L, needs=needs)
+        halo_pos: List[Dict[Tuple[int, int], int]] = []
+        for r in range(g):
+            pos = {tok: k for k, tok in enumerate(pattern.canonical_tokens(r))}
+            halo_pos.append(pos)
+        H = max(pattern.max_recv_size(), 1)
 
-    # 3. per-rank ELL blocks with rewritten column ids
-    kd = ko = 1
-    for r in range(g):
-        for i in range(r * L, (r + 1) * L):
-            cols, _ = matrix.row(i)
-            on = sum(owner(int(c)) == r for c in cols)
-            kd = max(kd, on)
-            ko = max(ko, len(cols) - on)
+    # 3. ELL widths of the diag and off blocks
+    with span("partition.widths"):
+        kd = ko = 1
+        for r in range(g):
+            for i in range(r * L, (r + 1) * L):
+                cols, _ = matrix.row(i)
+                on = sum(owner(int(c)) == r for c in cols)
+                kd = max(kd, on)
+                ko = max(ko, len(cols) - on)
 
-    diag_data = np.zeros((g, L, kd), dtype=np.float32)
-    diag_cols = np.zeros((g, L, kd), dtype=np.int32)
-    off_data = np.zeros((g, L, ko), dtype=np.float32)
-    off_cols = np.zeros((g, L, ko), dtype=np.int32)
-    off_row_nnz = np.zeros(g * L, dtype=np.int64)
-    for r in range(g):
-        for li in range(L):
-            cols, vals = matrix.row(r * L + li)
-            di = oi = 0
-            for c, vv in zip(cols, vals):
-                o = owner(int(c))
-                if o == r:
-                    diag_data[r, li, di] = vv
-                    diag_cols[r, li, di] = int(c) - r * L
-                    di += 1
-                else:
-                    off_data[r, li, oi] = vv
-                    off_cols[r, li, oi] = halo_pos[r][(o, int(c) - o * L)]
-                    oi += 1
-            off_row_nnz[r * L + li] = oi
+    # 4. per-rank ELL blocks with rewritten column ids
+    with span("partition.fill"):
+        diag_data = np.zeros((g, L, kd), dtype=np.float32)
+        diag_cols = np.zeros((g, L, kd), dtype=np.int32)
+        off_data = np.zeros((g, L, ko), dtype=np.float32)
+        off_cols = np.zeros((g, L, ko), dtype=np.int32)
+        off_row_nnz = np.zeros(g * L, dtype=np.int64)
+        for r in range(g):
+            for li in range(L):
+                cols, vals = matrix.row(r * L + li)
+                di = oi = 0
+                for c, vv in zip(cols, vals):
+                    o = owner(int(c))
+                    if o == r:
+                        diag_data[r, li, di] = vv
+                        diag_cols[r, li, di] = int(c) - r * L
+                        di += 1
+                    else:
+                        off_data[r, li, oi] = vv
+                        off_cols[r, li, oi] = halo_pos[r][(o, int(c) - o * L)]
+                        oi += 1
+                off_row_nnz[r * L + li] = oi
 
     return SpmvPartition(
         topo=topo,

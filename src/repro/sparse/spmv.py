@@ -75,6 +75,7 @@ from repro.kernels import ref as kref
 from repro.kernels.spmv_ell import TILE_R
 from repro.sparse.matrices import CSRMatrix
 from repro.sparse.partition import SpmvPartition, partition_csr
+from repro.trace import scope, span
 
 #: advisor Strategy -> executable strategy name (canonical copy lives with
 #: the advisor so the fault ladder's re-advising shares one mapping)
@@ -123,8 +124,12 @@ def _compute_program(
         def compute(v_local, halo, dd, dc, od, oc):
             # leading rank dim is 1 inside shard_map
             v_local, halo = v_local[0], halo[0]
-            w = local(dd[0], dc[0], v_local) + local(od[0], oc[0], halo)
-            return w[None]
+            with scope("spmv"):
+                with scope("spmv.diag"):
+                    w_diag = local(dd[0], dc[0], v_local)
+                with scope("spmv.off"):
+                    w_off = local(od[0], oc[0], halo)
+                return (w_diag + w_off)[None]
 
         return jax.jit(
             jax.shard_map(
@@ -175,7 +180,8 @@ def _phase_program(
                 return kref.spmm_ell_masked(data, cols, x, rows)
 
         def compute(x, data, cols, tiles, rows):
-            return local(data[0], cols[0], x[0], tiles[0], rows[0])[None]
+            with scope("spmv"):
+                return local(data[0], cols[0], x[0], tiles[0], rows[0])[None]
 
         return jax.jit(
             jax.shard_map(
@@ -280,38 +286,46 @@ class DistributedSpMV:
     health: Optional[object] = None
 
     def __post_init__(self) -> None:
+        with span("build"):
+            self._build()
+
+    def _build(self) -> None:
+        """Advise, plan the exchange, fetch the compiled programs and place
+        the blocks: host span ``repro.build``, with ``.advise`` and
+        ``.place``."""
         topo = self.partition.topo
         if self.strategy == "auto" or self.wire == "auto":
-            advice = advise(
-                self.partition.pattern.to_comm_pattern(),
-                machine="tpu_v5e_pod",
-                payload_width=self.payload_width,
-                # "auto" ranks every codec; a fixed codec constrains the
-                # candidate set; "none" keeps the paper's ranking
-                wire="auto" if self.wire == "auto" else (
-                    None if self.wire == "none" else self.wire
-                ),
-            )
-            self.advice = advice
-            best = advice.best
-            if self.strategy != "auto":
-                # wire="auto" with a pinned strategy: fastest codec among
-                # this strategy's own variants
-                best = next(
-                    (
-                        r for r in advice.ranked
-                        if _ADVISED[r.strategy] == self.strategy
+            with span("build.advise"):
+                advice = advise(
+                    self.partition.pattern.to_comm_pattern(),
+                    machine="tpu_v5e_pod",
+                    payload_width=self.payload_width,
+                    # "auto" ranks every codec; a fixed codec constrains the
+                    # candidate set; "none" keeps the paper's ranking
+                    wire="auto" if self.wire == "auto" else (
+                        None if self.wire == "none" else self.wire
                     ),
-                    None,
                 )
-                if best is None:
-                    raise ValueError(
-                        f"unknown strategy {self.strategy!r}; known: "
-                        f"{sorted(set(_ADVISED.values()))}"
+                self.advice = advice
+                best = advice.best
+                if self.strategy != "auto":
+                    # wire="auto" with a pinned strategy: fastest codec among
+                    # this strategy's own variants
+                    best = next(
+                        (
+                            r for r in advice.ranked
+                            if _ADVISED[r.strategy] == self.strategy
+                        ),
+                        None,
                     )
-            self.strategy = _ADVISED[best.strategy]
-            if self.wire == "auto":
-                self.wire = best.wire
+                    if best is None:
+                        raise ValueError(
+                            f"unknown strategy {self.strategy!r}; known: "
+                            f"{sorted(set(_ADVISED.values()))}"
+                        )
+                self.strategy = _ADVISED[best.strategy]
+                if self.wire == "auto":
+                    self.wire = best.wire
         else:
             self.advice = None
         if self.mesh is None:
@@ -342,10 +356,11 @@ class DistributedSpMV:
             self._fingerprint, self.mesh, self.use_pallas, None
         )
         # each device holds only its rank's slice of the ELL blocks
-        self._blocks = tuple(
-            shard_ranks(a.reshape(g, L, -1), self.mesh)
-            for a in (part.diag.data, part.diag.cols, part.off.data, part.off.cols)
-        )
+        with span("build.place"):
+            self._blocks = tuple(
+                shard_ranks(a.reshape(g, L, -1), self.mesh)
+                for a in (part.diag.data, part.diag.cols, part.off.data, part.off.cols)
+            )
         # per-instance memo over the module LRU: matmat's hot path must not
         # re-derive the (fingerprint, k, mesh) key per call
         self._mm_programs: dict = {}
@@ -372,17 +387,18 @@ class DistributedSpMV:
         (``[nranks, L, k]``) dispatches to :meth:`matmat`."""
         if v.ndim == 3:
             return self.matmat(v)
-        if not self.overlap:
-            halo = self.exchange(v)
-            return self._compute(v, halo, *self._blocks)
-        all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks
-        handle = self.exchange.start(v)
-        # the whole halo-independent diag block runs while the inter-pod
-        # phase is in flight; only boundary tiles' off-block waits on it
-        w_diag = self._phase_fn(v, *self._blocks[:2], all_tiles, all_rows)
-        halo = handle.finish()
-        w_off = self._phase_fn(halo, *self._blocks[2:], bnd_tiles, bnd_rows)
-        return w_diag + w_off
+        with span("spmv"):
+            if not self.overlap:
+                halo = self.exchange(v)
+                return self._compute(v, halo, *self._blocks)
+            all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks
+            handle = self.exchange.start(v)
+            # the whole halo-independent diag block runs while the inter-pod
+            # phase is in flight; only boundary tiles' off-block waits on it
+            w_diag = self._phase_fn(v, *self._blocks[:2], all_tiles, all_rows)
+            halo = handle.finish()
+            w_off = self._phase_fn(halo, *self._blocks[2:], bnd_tiles, bnd_rows)
+            return w_diag + w_off
 
     def matmat(self, V: jax.Array) -> jax.Array:
         """``V [nranks, L, k] -> W [nranks, L, k]`` under ONE exchange.
@@ -398,25 +414,26 @@ class DistributedSpMV:
         if V.ndim != 3:
             raise ValueError(f"matmat expects [nranks, L, k], got {tuple(V.shape)}")
         k = int(V.shape[2])
-        if not self.overlap:
-            halo = self.exchange(V)
-            fn = self._mm_programs.get(k)
+        with span("spmv"):
+            if not self.overlap:
+                halo = self.exchange(V)
+                fn = self._mm_programs.get(k)
+                if fn is None:
+                    fn = self._mm_programs[k] = _compute_program(
+                        self._fingerprint, self.mesh, self.use_pallas, k
+                    )
+                return fn(V, halo, *self._blocks)
+            fn = self._mm_phase_programs.get(k)
             if fn is None:
-                fn = self._mm_programs[k] = _compute_program(
+                fn = self._mm_phase_programs[k] = _phase_program(
                     self._fingerprint, self.mesh, self.use_pallas, k
                 )
-            return fn(V, halo, *self._blocks)
-        fn = self._mm_phase_programs.get(k)
-        if fn is None:
-            fn = self._mm_phase_programs[k] = _phase_program(
-                self._fingerprint, self.mesh, self.use_pallas, k
-            )
-        all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks
-        handle = self.exchange.start(V)
-        w_diag = fn(V, *self._blocks[:2], all_tiles, all_rows)
-        halo = handle.finish()
-        w_off = fn(halo, *self._blocks[2:], bnd_tiles, bnd_rows)
-        return w_diag + w_off
+            all_tiles, all_rows, bnd_tiles, bnd_rows = self._masks
+            handle = self.exchange.start(V)
+            w_diag = fn(V, *self._blocks[:2], all_tiles, all_rows)
+            halo = handle.finish()
+            w_off = fn(halo, *self._blocks[2:], bnd_tiles, bnd_rows)
+            return w_diag + w_off
 
     def matmat_looped(self, V: jax.Array) -> jax.Array:
         """Per-column baseline: ``k`` exchanges + ``k`` local SpMVs.
